@@ -43,8 +43,8 @@ from __future__ import annotations
 
 import time
 
+import jax
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core import market as M
 from repro.core import scenarios as SC
@@ -148,7 +148,7 @@ def run(quick: bool = False) -> dict:
 
     # x64 bit-identity: batched gather vs serial reference, row for row
     x64_trials = 40 if quick else 100
-    with enable_x64():
+    with jax.enable_x64(True):
         kw = dict(market=market, regimes=REGIMES, policies=POLICIES,
                   seeds=(0,), job_steps=min(job_steps, 120),
                   n_trials=x64_trials)

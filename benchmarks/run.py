@@ -1,9 +1,10 @@
 """Benchmark harness - one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows.  The dry-run/roofline numbers
-(deliverables e,g) are produced by ``repro.launch.dryrun`` (512-device
-placeholder mesh) and reported in EXPERIMENTS.md; this harness covers the
-paper's own tables/figures plus kernel and end-to-end microbenches.
+come from ``repro.launch.dryrun`` (512-device placeholder mesh); this harness
+covers the paper's own tables/figures plus kernel and end-to-end
+microbenches.  Its timings are host-clock rehearsals, not chip
+measurements.
 
 Usage::
 
@@ -20,6 +21,8 @@ import argparse
 import inspect
 import sys
 import traceback
+
+from repro import compile_cache
 
 from . import (e2e_train, fig1_fit, fig5_wasted_work, fig6_scheduling,
                fig7_checkpointing, fig8_service, kernels_bench, market_bench,
@@ -52,6 +55,7 @@ def main(argv=None) -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated module names to run")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.only is None:
         selected = MODULES
     else:

@@ -58,7 +58,6 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core import distributions as D
 from repro.core import engine as E
@@ -289,7 +288,7 @@ def run(quick: bool = False):
     # serial reference rows exactly (reduced trials keep the check cheap)
     n64 = 80 if quick else 250
     wk64 = dict(ck_workload, n_trials=n64)
-    with enable_x64():
+    with jax.enable_x64(True):
         rows64_b = SC.sweep_checkpointing(grid, policies=CKPT_POLICIES,
                                           seeds=seeds, **wk64)
         rows64_s = SC.sweep_checkpointing(grid, policies=CKPT_POLICIES,

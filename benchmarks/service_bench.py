@@ -31,6 +31,7 @@ import json
 import os
 import time
 
+import jax
 import numpy as np
 
 from repro.core import distributions as D
@@ -72,8 +73,7 @@ def _bench_fig8(quick: bool) -> dict:
     t_batched = time.perf_counter() - t0
 
     # repeat the comparison under x64, where the contract is bit-identity
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         bitexact = _rows_identical(SV.run_bag_grid(mode="serial", **kw),
                                    SV.run_bag_grid(mode="batched", **kw))
     emit(f"service/fig8_grid_n{kw['n_jobs']}", t_batched * 1e6,

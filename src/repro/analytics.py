@@ -8,7 +8,9 @@ cost_analysis under-reports FLOPs by ~n_layers x.  The dry-run reports both:
 HLO numbers for the compiled artifact, analytic numbers (cross-checked
 against an unrolled 1-group lowering in tests) for the roofline.
 
-Hardware constants: TPU v5e.
+Hardware constants: the published TPU v5e peaks, used only by the dry-run's
+analytic roofline estimate.  They are not measurements, and no chip path
+reads them.
 """
 from __future__ import annotations
 

@@ -223,8 +223,7 @@ class CheckpointManager:
             step >= self._next_ckpt_step
 
     def save(self, step: int, tree, metadata=None, *, emergency: bool = False):
-        if self._writer is not None:
-            self._writer.join()  # one in-flight write at a time
+        self.wait()  # one in-flight write at a time
         meta = dict(metadata or {})
         meta["policy"] = self.policy
         meta["emergency"] = emergency
@@ -241,9 +240,13 @@ class CheckpointManager:
         """The provider's 30 s warning: flush an emergency checkpoint NOW."""
         self.save(step, tree, metadata, emergency=True)
 
-    def restore(self, template):
+    def wait(self):
+        """Block until the in-flight checkpoint write (if any) is on disk."""
         if self._writer is not None:
             self._writer.join()
+
+    def restore(self, template):
+        self.wait()
         return restore_latest(self.directory, template)
 
     def on_restart(self, *, pod_age_hours: float = 0.0, resumed_step: int = 0):

@@ -184,7 +184,7 @@ class TrainConfig:
     # bounds activation peak memory at fixed global batch
     grad_accum: int = 1
     # preemption-aware checkpointing (the paper's policies)
-    ckpt_dir: str = "/tmp/repro_ckpt"
+    ckpt_dir: str = "runs/ckpt"     # relative to the working directory
     ckpt_policy: str = "dp"        # dp | young_daly | fixed | none
     ckpt_cost_hours: float = 1.0 / 60.0
     step_time_hours: float = 1.0 / 3600.0   # measured online; this is the seed

@@ -35,7 +35,7 @@ of the contract:
 
   * :func:`simulate_makespan_batch` vs the per-trial Python loop
     ``checkpointing.simulate_makespan`` — on a shared pre-drawn pool with x64
-    enabled (``jax.experimental.enable_x64``), makespans match bit-for-bit:
+    enabled (``jax.enable_x64(True)``), makespans match bit-for-bit:
     the kernel works in integer grid-step units with the only float
     accumulation (lost partial segments) ordered exactly as the reference,
     so XLA cannot contract a multiply-add into an FMA.  In default float32

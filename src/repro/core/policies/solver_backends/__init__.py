@@ -109,8 +109,6 @@ def shard_scenarios(fn, n_scenarios: int, n_args: int, n_out: int):
     mesh, pspec = scenario_partition(n_scenarios)
     if mesh is None:
         return fn, False
-    from jax.experimental.shard_map import shard_map
-
-    wrapped = shard_map(fn, mesh=mesh, in_specs=(pspec,) * n_args,
-                        out_specs=(pspec,) * n_out, check_rep=False)
+    wrapped = jax.shard_map(fn, mesh=mesh, in_specs=(pspec,) * n_args,
+                            out_specs=(pspec,) * n_out, check_vma=False)
     return wrapped, True

@@ -18,8 +18,10 @@ serial reference.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh
 
 # Shared guard against zero survival/failure mass in the conditional forms;
 # all backends must use this same constant so their per-element arithmetic
@@ -30,22 +32,39 @@ _EPS = 1e-9
 def cdf_grids(dist, grid_dt: float):
     """Build the (Fc, Hc) solver grids for one distribution.
 
-    Returns ``(Fc, Hc, t_max)`` where the grids have ``t_max + 1`` cells
+    Returns ``(Fc, Hc, t_max)`` where the grids are host numpy arrays with
+    ``t_max + 1`` cells
     (``t_max = round(L / grid_dt)``) and are pinned to the solver's native
     float32: a python-float scalar would trace as weak f64 under x64 and
     shift parts of the DP arithmetic to f64, where the reference and batched
     kernels round differently — pinning keeps every backend bit-comparable
     at any session dtype.
+
+    The grids are built on the host CPU whatever the default device.  The
+    DP is ill-conditioned in them: ``e_lost`` divides a difference of
+    partial expectations by a small CDF difference, so a 1-ulp change in
+    ``Fc``/``Hc`` moves V by ~3e-4 and flips ~4 % of K at J = 720,
+    T = 1441.  Accelerators evaluate ``exp``/``log`` to a few ulps, so
+    building the grids on the host is what makes tables solved on any
+    device comparable to the reference.
     """
-    L = float(dist.L)
-    t_max = int(round(L / grid_dt))
-    tk = jnp.arange(t_max + 1) * grid_dt
-    F_raw = jnp.clip(dist.cdf(tk), 0.0, 1.0)
-    atom = jnp.maximum(1.0 - F_raw[-1], 0.0)             # provider kill at L
-    Fc = F_raw.at[-1].set(1.0).astype(jnp.float32)
-    H_raw = dist.partial_expectation(jnp.zeros_like(tk), tk)
-    Hc = H_raw.at[-1].add(atom * L).astype(jnp.float32)
-    return Fc, Hc, t_max
+    host = jax.devices("cpu")[0]
+    dist = jax.tree_util.tree_map(
+        lambda x: jax.device_put(x, host) if isinstance(x, jax.Array) else x,
+        dist)
+    # a one-device host mesh, not ``jax.default_device``: a caller's
+    # ``jax.set_mesh`` would place the arrays created here on its mesh
+    with jax.set_mesh(Mesh(np.array([host]), ("host",))):
+        L = float(dist.L)
+        t_max = int(round(L / grid_dt))
+        tk = jnp.arange(t_max + 1) * grid_dt
+        F_raw = jnp.clip(dist.cdf(tk), 0.0, 1.0)
+        atom = jnp.maximum(1.0 - F_raw[-1], 0.0)         # provider kill at L
+        Fc = F_raw.at[-1].set(1.0).astype(jnp.float32)
+        H_raw = dist.partial_expectation(jnp.zeros_like(tk), tk)
+        Hc = H_raw.at[-1].add(atom * L).astype(jnp.float32)
+    # host numpy, so the solve that consumes them runs on the default device
+    return np.asarray(Fc), np.asarray(Hc), t_max
 
 
 def price_cum_grids(prices, cum, price_dt: float, grid_dt: float,

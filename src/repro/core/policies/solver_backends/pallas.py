@@ -53,13 +53,8 @@ def solve_tables_batch(Fc, Hc, grid_dt, restart_overhead, v_init=None,
         col0 = v_init[:, :, 0].astype(jnp.float32)
     if interpret is None:
         interpret = _interpret_default()
-    if Pc is None:
-        return dp_recurrence(
-            Fc, Hc, col0, grid_dt=float(grid_dt),
-            restart_overhead=float(restart_overhead), j_max=j_max,
-            t_max=t_max, delta_steps=delta_steps, n_sweeps=n_sweeps,
-            interpret=bool(interpret))
+    Ro = jnp.broadcast_to(jnp.asarray(restart_overhead, jnp.float32), (S,))
     return dp_recurrence(
-        Fc, Hc, col0, grid_dt=float(grid_dt), restart_overhead=0.0,
-        j_max=j_max, t_max=t_max, delta_steps=delta_steps, n_sweeps=n_sweeps,
-        interpret=bool(interpret), Pc=Pc, Ro=restart_overhead)
+        Fc, Hc, col0, Ro, grid_dt, j_max=j_max, t_max=t_max,
+        delta_steps=delta_steps, n_sweeps=n_sweeps,
+        interpret=bool(interpret), Pc=Pc)

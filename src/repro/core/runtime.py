@@ -38,8 +38,11 @@ NaN table:
   ``tracker.defer_refit`` (doubling, ``retry_backoff_obs * 2**k``).
 * solve stage — wall-clock budget (``SolveTimeout``), the injector's
   artificial timeouts, and table validation (``BatchDPTables.validate`` +
-  ``engine.validate_policy_table``) all degrade to the last-good tables;
-  a staleness counter runs from change-point confirmation to the swap.
+  ``engine.validate_policy_table``, raised as ``SolveInvalid``) all degrade
+  to the last-good tables; a staleness counter runs from change-point
+  confirmation to the swap.  Anything else the solver raises — a kernel
+  that fails to lower or compile, a bad argument — propagates: retrying
+  cannot fix it, and degrading would hide it.
 * instrumentation — adaptation lag (observations between an *injected*
   drift and the table swap that answers it) and stale-table makespan
   regret (paired pools: same lifetime draws, stale K vs fresh K), written
@@ -65,6 +68,10 @@ FLEET_VM_TYPES = ("n1-highcpu-2", "n1-highcpu-4", "n1-highcpu-8",
 
 class SolveTimeout(RuntimeError):
     """A DP re-solve exceeded its wall-clock budget (real or injected)."""
+
+
+class SolveInvalid(ValueError):
+    """A DP re-solve returned tables that failed validation."""
 
 
 @dataclasses.dataclass
@@ -278,9 +285,12 @@ class FleetRuntime:
         if dt > cfg.solve_budget_s:
             raise SolveTimeout(f"solve took {dt:.2f}s "
                                f"(budget {cfg.solve_budget_s}s)")
-        tab.validate()
-        for s in range(len(tab)):
-            engine.validate_policy_table(tab.K[s])
+        try:
+            tab.validate()
+            for s in range(len(tab)):
+                engine.validate_policy_table(tab.K[s])
+        except ValueError as e:
+            raise SolveInvalid(str(e)) from e
         self._last_solve_warm = warm
         self._last_solve_seconds = dt
         return tab
@@ -290,7 +300,7 @@ class FleetRuntime:
         tables and schedule a bounded backoff retry."""
         try:
             tab = self._solve(warm=True)
-        except (SolveTimeout, ValueError) as e:
+        except (SolveTimeout, SolveInvalid) as e:
             self.retries["solve"] += 1
             self._solve_attempts += 1
             self._pending_swap = reason
@@ -326,11 +336,11 @@ class FleetRuntime:
         regret = None
         if reason == "change-point":
             # what the displaced (now-stale) table was costing, measured on
-            # the model the fleet just adapted to; instrumentation must
-            # never take the loop down, so probe failures record as None
+            # the model the fleet just adapted to; a numerical probe
+            # failure records as None, anything else is a fault and raises
             try:
                 regret = self.measure_regret()
-            except Exception:
+            except ArithmeticError:
                 regret = None
         self.swaps.append(SwapRecord(
             obs=self.obs, reason=reason, warm=self._last_solve_warm,

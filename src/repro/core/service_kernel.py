@@ -25,7 +25,7 @@ convention of ``engine.simulate_makespan_batch``).
 
 Bit-exactness contract
 ----------------------
-Under ``jax.experimental.enable_x64`` and a shared lifetime pool, a lane is
+Under ``jax.enable_x64(True)`` and a shared lifetime pool, a lane is
 bit-identical to ``service.BatchService.run`` — per-job completion times,
 failure/attempt counts, ``vm_hours`` and the cost accounting all match the
 serial heap loop float-for-float.  This holds because every arithmetic

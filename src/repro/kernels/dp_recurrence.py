@@ -1,38 +1,46 @@
 """Checkpointing-DP inner recurrence (Eqs. 11-15) as a Pallas TPU kernel.
 
-Grid = ``(S,)``: one program per scenario, with the whole per-scenario DP —
-``n_sweeps`` restart-cost fixed-point sweeps x ``j_max`` rows — run inside
-the program so the value table never leaves VMEM.  Layout per program:
+Grid = ``(S_pad / 8,)``: one program per block of eight scenarios, with the
+whole DP of the block — ``n_sweeps`` restart-cost fixed-point sweeps x
+``j_max`` rows — run inside the program so the value table never leaves
+VMEM.  Layout per program:
 
-  * the VM-age axis lives on the lanes: every row is a ``(1, T_pad)`` f32
-    vector, so one candidate evaluation is a handful of W-wide VPU
-    multiply-adds;
-  * the j-loop's min-reduce over candidate intervals is a blocked
-    sequential scan: candidates ``i = 1..j`` stream one at a time, each
-    updating a running ``(1, T_pad)`` min (strict ``<`` on an ascending
-    scan keeps the reference's first-match argmin for ``K``);
-  * the value table is a persistent ``(j_max+1, T_pad)`` VMEM scratch whose
-    tail padding holds each row's horizon value, so the reference's
-    ``clip(t + w, 0, t_max)`` age gather becomes a plain shifted row load.
+  * scenarios on the 8 sublanes and the VM-age axis on the lanes: every row
+    of the value table is one ``(8, Tp)`` f32 slab (``Tp`` = ``t_max + 1``
+    rounded up to 128), so one candidate evaluation is a handful of
+    full-vreg VPU multiply-adds;
+  * the j-loop's min-reduce over candidate intervals is a sequential scan:
+    candidates ``i = 1..j`` stream one at a time, each updating a running
+    ``(8, Tp)`` min (strict ``<`` on an ascending scan keeps the
+    reference's first-match argmin for ``K``);
+  * the value table is a persistent ``(j_max+1, 8, TB)`` VMEM scratch whose
+    lanes past ``Tp`` hold each row's horizon value, so the reference's
+    ``clip(t + w, 0, t_max)`` age gather becomes a shifted row read.  A
+    shift by ``w`` is a 128-aligned dynamic lane window plus a lane rotate
+    by ``w mod 128`` (``pltpu.roll``): Mosaic loads only at lane offsets it
+    can prove aligned.
+
+V and K rows of the last sweep go straight to HBM by DMA, so only the
+value table and the per-row restart costs occupy VMEM
+(:func:`vmem_bytes`).
 
 Unlike the XLA backend — which hoists ``(T, I)`` probability/loss grids per
 scenario — this kernel recomputes ``p_fail``/``e_lost`` on the fly from the
-``(1, T_pad)`` CDF rows as shifted-slice arithmetic: nothing larger than the
-value table is ever materialized, which is what lets market-scale scenario
-counts fit one core's VMEM.  The trade is bit-exactness: recomputation under
-a different fusion schedule rounds differently at ULP scale, so this backend
+CDF rows as shifted-slab arithmetic: nothing larger than the value table is
+ever materialized.  The trade is bit-exactness: recomputation under a
+different fusion schedule rounds differently at ULP scale, so this backend
 is tolerance-tested against the reference, not bit-pinned (see
 ``docs/solver.md``).
 
-The dollar objective adds one more ``(1, T_pad)`` row per program — the
-cumulative-dollar grid ``Pc`` (``grids.price_cum_grids``) — and a per-program
-scalar dollar restart overhead: segment dollars ``dP = Pc[t+w] - Pc[t]`` are
-the same shifted-slice pattern as the CDF deltas.  The cumulative row is
-built host-side on the extended age axis, so edge padding beyond it only
-ever feeds dead lanes (whose values are overwritten with ``Rj``).
+The dollar objective adds one more row per program — the cumulative-dollar
+grid ``Pc`` (``grids.price_cum_grids``): segment dollars
+``dP = Pc[t+w] - Pc[t]`` are the same shifted-slab pattern as the CDF
+deltas.  The cumulative row is built host-side on the extended age axis, so
+edge padding beyond it only ever feeds dead lanes (whose values are
+overwritten with ``Rj``).
 
-Oracle: ``solver_backends.reference``.  On CPU containers the kernel runs
-with ``interpret=True`` (tests/test_solver_backends.py, marker ``pallas``).
+Oracle: ``solver_backends.reference``.  Off-TPU the kernel runs with
+``interpret=True`` (tests/test_solver_backends.py, marker ``pallas``).
 """
 from __future__ import annotations
 
@@ -44,149 +52,182 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _EPS = 1e-9
+SUB = 8            # scenarios per program: one per sublane
+LANE = 128
 
 
-def _dp_kernel(*refs, dt: float, restart_overhead: float, j_max: int,
-               t_max: int, delta_steps: int, n_sweeps: int, TPL: int,
-               TB: int, price: bool):
-    if price:
-        fc_ref, hc_ref, c0_ref, pc_ref, ro_ref, v_out, k_out, \
-            v_scr, c0_scr = refs
-    else:
-        fc_ref, hc_ref, c0_ref, v_out, k_out, v_scr, c0_scr = refs
-        pc_ref = ro_ref = None
-    T = t_max + 1
-    dtf = jnp.float32(dt)
-    rof = jnp.float32(restart_overhead)
-    fc = fc_ref[...]                                  # (1, TB)
-    hc = hc_ref[...]
-    Ft = fc[:, :TPL]
-    Ht = hc[:, :TPL]
-    St = jnp.maximum(1.0 - Ft, _EPS)
-    dead = (1.0 - Ft) < 1e-6                          # padded lanes: Fc=1
-    t_dt = jax.lax.broadcasted_iota(jnp.int32, (1, TPL), 1) * dtf
-    if price:
-        pc = pc_ref[...]                              # (1, TB) cumulative $
-        Pt = pc[:, :TPL]
-        rof = ro_ref[0, 0]                            # per-scenario $ overhead
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
-    # row 0 (job done): V = 0 at every age, including the horizon padding
-    v_scr[0, :] = jnp.zeros((TB,), jnp.float32)
-    v_out[0, 0, :] = jnp.zeros((T,), jnp.float32)
-    k_out[0, 0, :] = jnp.zeros((T,), jnp.int32)
-    # restart-cost column seed (cold j*dt or the warm-start V's column 0)
-    c0_scr[...] = c0_ref[...]
 
-    def sweep(_s, carry):
-        r = rof + c0_scr[...]                         # (1, j_max+1) snapshot
+def _widths(j_max: int, t_max: int, delta_steps: int):
+    """(Tp, TB): compute width and value-table row width in lanes.  Reads
+    shift by at most ``j_max + delta_steps`` and load a ``Tp + 128`` window
+    from a 128-aligned base at or below the shift."""
+    tp = _round_up(t_max + 1, LANE)
+    return tp, tp + _round_up(j_max + delta_steps + 1, LANE)
+
+
+def vmem_bytes(j_max: int, t_max: int, delta_steps: int) -> int:
+    """VMEM the kernel's scratch and double-buffered input rows take."""
+    tp, tb = _widths(j_max, t_max, delta_steps)
+    table = (j_max + 1) * SUB * tb * 4
+    restart = (j_max + 1) * SUB * LANE * 4
+    rows = 2 * (3 * SUB * tb + SUB * LANE) * 4
+    stage = SUB * tp * 4
+    return table + restart + rows + stage
+
+
+def _dp_kernel(dt_ref, fc_ref, hc_ref, pc_ref, ro_ref, c0_hbm, v_hbm, k_hbm,
+               v_scr, r_scr, k_stage, *, j_max: int, t_max: int,
+               delta_steps: int, n_sweeps: int, Tp: int, TB: int,
+               price: bool):
+    W = Tp + LANE                                     # loaded window width
+    s0 = pl.program_id(0) * SUB
+    dtf = dt_ref[0]
+
+    def shifted(load, w):
+        """``row[:, w:w+Tp]`` of an (8, TB) row, from an aligned window."""
+        base = pl.multiple_of((w // LANE) * LANE, LANE)
+        win = load(pl.ds(base, W))
+        return pltpu.roll(win, (W - (w - base)) % W, 1)[:, :Tp]
+
+    # row 0 (job done): V = 0 at every age, including the horizon lanes
+    v_scr[0] = jnp.zeros((SUB, TB), jnp.float32)
+    # restart-cost column seed (cold j*dt / Pc, or a warm start's column 0)
+    pltpu.sync_copy(c0_hbm.at[:, pl.ds(s0, SUB), :], r_scr)
+
+    def sweep(s, carry):
+        ro = ro_ref[...]                              # (8, 128) overhead
+
+        def restart(m, c):
+            # R[m] = overhead + V[m, 0] of the previous sweep (or the seed)
+            prev = jnp.where(s == 0, r_scr[m],
+                             jnp.broadcast_to(v_scr[m, :, 0:1], (SUB, LANE)))
+            r_scr[m] = ro + prev
+            return c
+
+        jax.lax.fori_loop(0, j_max + 1, restart, 0)
 
         def row(j, carry):
-            Rj = r[0, j]
-            m0 = jnp.full((1, TPL), jnp.inf, jnp.float32)
-            k0 = jnp.zeros((1, TPL), jnp.int32)
+            Rj = r_scr[j][:, 0:1]                     # (8, 1)
+            Ft = fc_ref[:, :Tp]
+            St = jnp.maximum(1.0 - Ft, _EPS)
+            dead = (1.0 - Ft) < 1e-6                  # padded lanes: Fc = 1
 
             def cand(i, mk):
                 m, k = mk
                 w = jnp.where(i == j, i, i + delta_steps)
-                Fe = jax.lax.dynamic_slice(fc, (0, w), (1, TPL))
-                He = jax.lax.dynamic_slice(hc, (0, w), (1, TPL))
+                wdt = w.astype(jnp.float32) * dtf
+                Ft = fc_ref[:, :Tp]
+                Ht = hc_ref[:, :Tp]
+                t_dt = jax.lax.broadcasted_iota(
+                    jnp.int32, (SUB, Tp), 1).astype(jnp.float32) * dtf
+                Fe = shifted(lambda sl: fc_ref[:, sl], w)
+                He = shifted(lambda sl: hc_ref[:, sl], w)
+                vrow = shifted(lambda sl: v_scr[j - i, :, sl], w)
                 p_fail = jnp.clip((Fe - Ft) / St, 0.0, 1.0)
                 dF = jnp.maximum(Fe - Ft, _EPS)
                 e_lost = (He - Ht) / dF - t_dt
-                e_lost = jnp.clip(e_lost, 0.0, w * dtf)
-                vrow = pl.load(v_scr, (pl.ds(j - i, 1), pl.ds(w, TPL)))
+                e_lost = jnp.clip(e_lost, 0.0, wdt)
                 if price:
-                    Pe = jax.lax.dynamic_slice(pc, (0, w), (1, TPL))
-                    dP = Pe - Pt
-                    pb = dP / (w * dtf)
+                    dP = shifted(lambda sl: pc_ref[:, sl], w) - pc_ref[:, :Tp]
+                    pb = dP / wdt
                     v_succ = dP + vrow
                     cost = (1.0 - p_fail) * v_succ \
                         + p_fail * (e_lost * pb + Rj)
                 else:
-                    v_succ = w * dtf + vrow
+                    v_succ = wdt + vrow
                     cost = (1.0 - p_fail) * v_succ + p_fail * (e_lost + Rj)
                 upd = cost < m
                 return jnp.where(upd, cost, m), jnp.where(upd, i, k)
 
+            m0 = jnp.full((SUB, Tp), jnp.inf, jnp.float32)
+            k0 = jnp.zeros((SUB, Tp), jnp.int32)
             m, k = jax.lax.fori_loop(1, j + 1, cand, (m0, k0))
             vj = jnp.where(dead, Rj, m)
-            kj = jnp.where(dead, jnp.minimum(j, j_max), k)
-            # persist the row: computed lanes, then horizon padding (age >=
+            # persist the row: computed lanes, then horizon lanes (age >=
             # t_max means a dead VM, whose value is exactly Rj)
-            pl.store(v_scr, (pl.ds(j, 1), pl.ds(0, TPL)), vj)
-            pl.store(v_scr, (pl.ds(j, 1), pl.ds(TPL, TB - TPL)),
-                     jnp.broadcast_to(Rj, (1, TB - TPL)))
-            pl.store(c0_scr, (pl.ds(0, 1), pl.ds(j, 1)), vj[:, 0:1])
-            pl.store(v_out, (pl.ds(0, 1), pl.ds(j, 1), pl.ds(0, T)),
-                     vj[:, :T].reshape(1, 1, T))
-            pl.store(k_out, (pl.ds(0, 1), pl.ds(j, 1), pl.ds(0, T)),
-                     kj[:, :T].reshape(1, 1, T))
+            v_scr[j, :, :Tp] = vj
+            v_scr[j, :, Tp:] = jnp.broadcast_to(Rj, (SUB, TB - Tp))
+
+            @pl.when(s == n_sweeps - 1)
+            def _():
+                k_stage[...] = jnp.where(dead, jnp.minimum(j, j_max), k)
+                pltpu.sync_copy(v_scr.at[j, :, pl.ds(0, Tp)],
+                                v_hbm.at[j, pl.ds(s0, SUB), :])
+                pltpu.sync_copy(k_stage, k_hbm.at[j, pl.ds(s0, SUB), :])
+
             return carry
 
         return jax.lax.fori_loop(1, j_max + 1, row, carry)
 
     jax.lax.fori_loop(0, n_sweeps, sweep, 0)
+    k_stage[...] = jnp.zeros((SUB, Tp), jnp.int32)
+    pltpu.sync_copy(v_scr.at[0, :, pl.ds(0, Tp)], v_hbm.at[0, pl.ds(s0, SUB), :])
+    pltpu.sync_copy(k_stage, k_hbm.at[0, pl.ds(s0, SUB), :])
 
 
-def dp_recurrence(Fc, Hc, col0, *, grid_dt: float, restart_overhead: float,
-                  j_max: int, t_max: int, delta_steps: int, n_sweeps: int,
-                  interpret: bool = False, Pc=None, Ro=None):
+def dp_recurrence(Fc, Hc, col0, Ro, grid_dt, *, j_max: int, t_max: int,
+                  delta_steps: int, n_sweeps: int, interpret: bool = False,
+                  Pc=None):
     """Solve the batched checkpointing DP.
 
     Fc, Hc: (S, t_max+1) f32 CDF / partial-expectation grids (see
     ``solver_backends.grids``); col0: (S, j_max+1) f32 seed for the
-    restart-cost column (cold ``j*dt`` or a warm start's ``V[:, :, 0]``).
+    restart-cost column (cold ``j*dt`` or a warm start's ``V[:, :, 0]``);
+    Ro: (S,) f32 restart overhead in the objective's unit; grid_dt: the
+    age-grid step in hours (a scalar, traced or not).
     Returns (V, K) of shapes (S, j_max+1, t_max+1).
 
     Dollar objective: ``Pc`` is the (S, t_max+1+j_max+delta_steps) f32
-    cumulative-dollar grid and ``Ro`` the (S,) f32 dollar restart overhead
-    (``restart_overhead`` is then ignored).  ``col0`` must be the dollar
-    seed (``Pc[:, :j_max+1]`` cold, or a warm dollar table's column 0).
+    cumulative-dollar grid.  ``col0`` must be the dollar seed
+    (``Pc[:, :j_max+1]`` cold, or a warm dollar table's column 0).
     """
     S, T = Fc.shape
-    assert T == t_max + 1, (T, t_max)
+    if T != t_max + 1:
+        raise ValueError(f"grid width {T} != t_max + 1 = {t_max + 1}")
     price = Pc is not None
-    pad = j_max + delta_steps + 8        # max age shift is j_max + delta
-    TPL = T + pad                        # compute width (tail lanes: dead)
-    TB = TPL + pad                       # buffer width for shifted loads
-    fc = jnp.pad(Fc, ((0, 0), (0, TB - T)), mode="edge")
-    hc = jnp.pad(Hc, ((0, 0), (0, TB - T)), mode="edge")
+    Tp, TB = _widths(j_max, t_max, delta_steps)
+    S_pad = _round_up(S, SUB)
+
+    def rows(x):          # (S, n) -> (S_pad, TB), edge-padded both ways
+        x = jnp.asarray(x, jnp.float32)
+        return jnp.pad(x, ((0, S_pad - S), (0, TB - x.shape[1])), mode="edge")
+
+    fc, hc = rows(Fc), rows(Hc)
+    pc = rows(Pc) if price else jnp.zeros((S_pad, TB), jnp.float32)
+    ro = jnp.broadcast_to(rows(jnp.reshape(Ro, (S, 1)))[:, :1], (S_pad, LANE))
+    c0 = jnp.pad(jnp.asarray(col0, jnp.float32), ((0, S_pad - S), (0, 0)),
+                 mode="edge")
+    c0 = jnp.broadcast_to(c0.T[:, :, None], (j_max + 1, S_pad, LANE))
+    dt = jnp.reshape(jnp.asarray(grid_dt, jnp.float32), (1,))
     kernel = functools.partial(
-        _dp_kernel, dt=float(grid_dt), restart_overhead=float(restart_overhead),
-        j_max=j_max, t_max=t_max, delta_steps=delta_steps, n_sweeps=n_sweeps,
-        TPL=TPL, TB=TB, price=price)
-    in_specs = [
-        pl.BlockSpec((1, TB), lambda s: (s, 0)),
-        pl.BlockSpec((1, TB), lambda s: (s, 0)),
-        pl.BlockSpec((1, j_max + 1), lambda s: (s, 0)),
-    ]
-    inputs = [fc, hc, col0]
-    if price:
-        # the extended Pc axis already covers every live-lane gather
-        # (t < T, shift <= j_max + delta); edge padding past it only feeds
-        # dead lanes whose values are overwritten with Rj
-        assert Ro is not None, "dollar mode needs the (S,) dollar overhead"
-        pc = jnp.pad(jnp.asarray(Pc, jnp.float32),
-                     ((0, 0), (0, TB - Pc.shape[1])), mode="edge")
-        in_specs += [pl.BlockSpec((1, TB), lambda s: (s, 0)),
-                     pl.BlockSpec((1, 1), lambda s: (s, 0))]
-        inputs += [pc, jnp.asarray(Ro, jnp.float32).reshape(S, 1)]
+        _dp_kernel, j_max=j_max, t_max=t_max, delta_steps=delta_steps,
+        n_sweeps=n_sweeps, Tp=Tp, TB=TB, price=price)
+    row_spec = pl.BlockSpec((SUB, TB), lambda b: (b, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     V, K = pl.pallas_call(
         kernel,
-        grid=(S,),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, j_max + 1, T), lambda s: (s, 0, 0)),
-            pl.BlockSpec((1, j_max + 1, T), lambda s: (s, 0, 0)),
-        ],
+        grid=(S_pad // SUB,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  row_spec, row_spec, row_spec,
+                  pl.BlockSpec((SUB, LANE), lambda b: (b, 0)), hbm],
+        out_specs=[hbm, hbm],
         out_shape=[
-            jax.ShapeDtypeStruct((S, j_max + 1, T), jnp.float32),
-            jax.ShapeDtypeStruct((S, j_max + 1, T), jnp.int32),
+            jax.ShapeDtypeStruct((j_max + 1, S_pad, Tp), jnp.float32),
+            jax.ShapeDtypeStruct((j_max + 1, S_pad, Tp), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((j_max + 1, TB), jnp.float32),
-            pltpu.VMEM((1, j_max + 1), jnp.float32),
+            pltpu.VMEM((j_max + 1, SUB, TB), jnp.float32),
+            pltpu.VMEM((j_max + 1, SUB, LANE), jnp.float32),
+            pltpu.VMEM((SUB, Tp), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_bytes(j_max, t_max, delta_steps) + (8 << 20)),
         interpret=interpret,
-    )(*inputs)
-    return V, K
+    )(dt, fc, hc, pc, ro, c0)
+    # rows were written j-major so each DMA is one aligned (8, Tp) tile run
+    return (jnp.transpose(V[:, :S, :T], (1, 0, 2)),
+            jnp.transpose(K[:, :S, :T], (1, 0, 2)))

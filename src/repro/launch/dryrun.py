@@ -19,8 +19,8 @@ from .mesh import make_production_mesh        # noqa: E402
 """Multi-pod dry-run: prove every (architecture x input shape x mesh) cell
 lowers, GSPMD-partitions, and compiles on the production meshes - 16x16
 ("data","model") single pod and 2x16x16 ("pod","data","model") multi-pod -
-and extract the memory / FLOP / collective numbers the roofline analysis
-(EXPERIMENTS.md §Roofline) consumes.
+and extract the memory / FLOP / collective numbers of the analytic roofline
+estimate (``repro.analytics``).
 
 Run:  PYTHONPATH=src python -m repro.launch.dryrun [--arch A] [--shape S]
           [--multi-pod] [--rules fsdp] [--out results.json]
@@ -61,7 +61,7 @@ def _cost_summary(compiled):
 
 
 # per-arch microbatch accumulation for train_4k: picked so activation peak
-# fits 16 GB/chip HBM under the fsdp rule set (see EXPERIMENTS.md §Perf)
+# fits 16 GB/chip HBM under the fsdp rule set
 DEFAULT_ACCUM = {
     "deepseek-coder-33b": 4, "yi-34b": 4, "recurrentgemma-2b": 4,
     "xlstm-1.3b": 8, "moonshot-v1-16b-a3b": 2, "qwen2-vl-2b": 2,
@@ -99,7 +99,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     t0 = time.time()
     try:
         mesh = make_production_mesh(multi_pod=multi_pod)
-        with mesh, sharding.use(mesh, rules):
+        with jax.set_mesh(mesh), sharding.use(mesh, rules):
             in_sh, out_sh, args, _ = steps.shardings_for_cell(
                 cfg, shape, mesh, rules)
             if shape.kind == "train":

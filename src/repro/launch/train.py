@@ -24,14 +24,13 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
-from typing import Optional
 
 import jax
 import numpy as np
 
-from .. import configs, sharding
+from .. import compile_cache, configs, sharding
 from ..checkpoint import CheckpointManager
-from ..configs.base import TrainConfig
+from ..configs.base import ShapeConfig, TrainConfig
 from ..core import distributions
 from ..data.pipeline import SyntheticLM
 from ..fault import PreemptionSource, StragglerWatchdog
@@ -49,6 +48,10 @@ class TrainResult:
     emergency_checkpoints: int
     wasted_steps: int
     final_loss: float
+    # one entry per injected preemption: the step it struck at and the
+    # step the restored checkpoint resumed from
+    preempted_at: list = dataclasses.field(default_factory=list)
+    resumed_from: list = dataclasses.field(default_factory=list)
 
 
 def train(cfg, tc: TrainConfig, *, total_steps: int = 200,
@@ -56,15 +59,28 @@ def train(cfg, tc: TrainConfig, *, total_steps: int = 200,
           inject_preemptions: bool = False, sim_hours_per_step: float = 0.02,
           preemption_seed: int = 7, mesh=None, rules: str = "baseline",
           log_every: int = 25, verbose: bool = True) -> TrainResult:
+    """Train ``cfg`` for ``total_steps``.  With a ``mesh``, parameters,
+    optimizer state and batches are placed by the ``rules`` logical-axis
+    table (``steps.shardings_for_cell``) and the step is jitted with those
+    shardings; without one, everything sits on the default device."""
     dist = distributions.constrained_for(tc.vm_type)
     pipe = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=seq_len,
                        global_batch=global_batch, seed=tc.seed)
     key = jax.random.PRNGKey(tc.seed)
 
-    params, _ = T.init(cfg, key)
+    params, axes = T.init(cfg, key)
     opt_state = adamw_init(params)
-    step_fn = steps.make_train_step(cfg, tc)
-    jitted = jax.jit(step_fn)
+    if mesh is None:
+        place = lambda tree, i: tree
+        jitted = jax.jit(steps.make_train_step(cfg, tc))
+    else:
+        in_sh, out_sh, _, _ = steps.shardings_for_cell(
+            cfg, ShapeConfig("train", "train", seq_len, global_batch), mesh,
+            rules)
+        place = lambda tree, i: jax.device_put(tree, in_sh[i])
+        jitted = jax.jit(steps.make_train_step(cfg, tc, param_axes=axes),
+                         in_shardings=in_sh, out_shardings=out_sh)
+        params, opt_state = place(params, 0), place(opt_state, 1)
 
     mgr = CheckpointManager(
         directory=tc.ckpt_dir, dist=dist, policy=tc.ckpt_policy,
@@ -78,9 +94,11 @@ def train(cfg, tc: TrainConfig, *, total_steps: int = 200,
     step = 0
     restarts = 0
     wasted = 0
+    preempted_at, resumed_from = [], []
     restored = mgr.restore((params, opt_state))
     if restored is not None:
         (params, opt_state), step, _ = restored
+        params, opt_state = place(params, 0), place(opt_state, 1)
         if verbose:
             print(f"resumed from checkpoint at step {step}")
 
@@ -88,8 +106,9 @@ def train(cfg, tc: TrainConfig, *, total_steps: int = 200,
     sim_now = 0.0
     while step < total_steps:
         t0 = time.time()
-        batch = pipe.batch(step)
-        params, opt_state, metrics = jitted(params, opt_state, batch)
+        batch = place(pipe.batch(step), 2)
+        with sharding.use(mesh, rules):   # the model's logical constraints
+            params, opt_state, metrics = jitted(params, opt_state, batch)
         loss = float(metrics["loss"])
         losses.append(loss)
         step += 1
@@ -116,6 +135,9 @@ def train(cfg, tc: TrainConfig, *, total_steps: int = 200,
                 restored = mgr.restore((params, opt_state))
                 assert restored is not None
                 (params, opt_state), ckpt_step, _ = restored
+                params, opt_state = place(params, 0), place(opt_state, 1)
+                preempted_at.append(step)
+                resumed_from.append(ckpt_step)
                 wasted += step - ckpt_step
                 step = ckpt_step
                 mgr.on_restart(pod_age_hours=0.0, resumed_step=step)
@@ -123,11 +145,13 @@ def train(cfg, tc: TrainConfig, *, total_steps: int = 200,
                     print(f"  !! pod preempted at sim t={sim_now:.2f}h -> "
                           f"restart from step {step}")
 
+    mgr.wait()
     return TrainResult(losses=losses, steps_run=len(losses),
                        restarts=restarts, checkpoints=mgr.n_saved,
                        emergency_checkpoints=mgr.n_emergency,
                        wasted_steps=wasted,
-                       final_loss=float(np.mean(losses[-10:])))
+                       final_loss=float(np.mean(losses[-10:])),
+                       preempted_at=preempted_at, resumed_from=resumed_from)
 
 
 def main():
@@ -139,8 +163,9 @@ def main():
     ap.add_argument("--preemptions", action="store_true")
     ap.add_argument("--ckpt-policy", default="dp",
                     choices=("dp", "young_daly", "fixed", "none"))
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=TrainConfig.ckpt_dir)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = configs.smoke(args.arch) if args.smoke else configs.get(args.arch)
     tc = TrainConfig(ckpt_policy=args.ckpt_policy, ckpt_dir=args.ckpt_dir,

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import distributions as D
 from repro.core import engine as E
@@ -147,7 +146,7 @@ def test_pool_batch_bitexact_x64(grid_dists):
     bit-for-bit (shared seed, shared draw order), including the conditioned
     first draw of an aged VM."""
     n, mr = 200, 16
-    with enable_x64():
+    with jax.enable_x64(True):
         for start_age in (0.0, 6.0):
             first_b, pool_b = E.draw_lifetime_pool_batch(
                 grid_dists, n, max_restarts=mr, seed=11, start_age=start_age)
@@ -201,7 +200,7 @@ def test_batched_executor_bitexact_per_slice(grid_dists):
     shared = E.no_checkpoint_policy_table(job)          # 2-D, broadcast
     first_b, pool_b = E.draw_lifetime_pool_batch(ds, 150, max_restarts=16,
                                                  seed=5)
-    with enable_x64():
+    with jax.enable_x64(True):
         for table_b, table_of in [(tables3, lambda s: tables3[s]),
                                   (shared, lambda s: shared)]:
             mk_b = E.simulate_makespan_batch(
@@ -258,7 +257,7 @@ def test_indexed_executor_matches_materialized(grid_dists):
     cells = [(s, r, p) for s in range(2) for r in range(2) for p in range(2)]
     tix = np.array([s if p == 0 else 2 for s, r, p in cells], np.int32)
     pix = np.array([s * 2 + r for s, r, p in cells], np.int32)
-    with enable_x64():
+    with jax.enable_x64(True):
         mk_idx = E.simulate_makespan_batch(
             uniq, job, first=first_q[pix], pool=pool_q, grid_dt=GRID,
             max_restarts=8, unfinished="partial",
@@ -336,7 +335,7 @@ def test_executor_dollar_rows_bitexact_x64(grid_dists):
     grid = M.MarketModel(
         processes=[M.spot_price_process(z) for z in M.MARKET_ZONE_PARAMS],
         horizon=12.0, seed=3).grid()
-    with enable_x64():
+    with jax.enable_x64(True):
         mk, fin, dollars = E.simulate_makespan_batch(
             tables3, job, first=first_b, pool=pool_b, grid_dt=GRID,
             max_restarts=2, return_finished=True, price=grid)
@@ -443,7 +442,7 @@ def test_one_kernel_unfinished_rows_match_serial():
     them."""
     kw = dict(policies=("dp", "none"), seeds=(0, 3), job_steps=30,
               n_trials=24, max_restarts=0)
-    with enable_x64():
+    with jax.enable_x64(True):
         rows_b = SC.sweep_checkpointing(_sweep_scenarios(), mode="batched",
                                         **kw)
         rows_s = SC.sweep_checkpointing(_sweep_scenarios(), mode="serial",
@@ -495,7 +494,7 @@ if st is not None:
         plus unflattening — are exactly the serial reference's rows under
         x64, NaN flags included."""
         kw = dict(job_steps=30, n_trials=24, **case)
-        with enable_x64():
+        with jax.enable_x64(True):
             rows_b = SC.sweep_checkpointing(_sweep_scenarios(),
                                             mode="batched", **kw)
             rows_s = SC.sweep_checkpointing(_sweep_scenarios(),

@@ -24,6 +24,7 @@ def test_elastic_pod_loss_resume(tmp_path):
         from repro.data.pipeline import SyntheticLM
         from repro.fault import plan_elastic_remesh
         from repro.launch import steps
+        from repro.launch.mesh import make_mesh
         from repro.models import transformer as T
         from repro.optim import adamw_init
 
@@ -39,7 +40,7 @@ def test_elastic_pod_loss_resume(tmp_path):
 
         def run(mesh, rules, start, end, params=None, opt=None):
             shape = ShapeConfig("t", "train", 32, 8)
-            with mesh, sharding.use(mesh, rules):
+            with jax.set_mesh(mesh), sharding.use(mesh, rules):
                 in_sh, out_sh, args, _ = steps.shardings_for_cell(
                     cfg, shape, mesh, rules)
                 fn = steps.make_train_step(cfg, tc)
@@ -60,14 +61,14 @@ def test_elastic_pod_loss_resume(tmp_path):
                 return params, opt, losses
 
         # phase 1: 2 pods (2,2,2) mesh
-        mesh2 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh2 = make_mesh((2, 2, 2), ("pod", "data", "model"))
         params, opt, l1 = run(mesh2, "fsdp", 0, 5)
         mgr.save(5, (params, opt))
 
         # pod 1 preempted -> survivor plan: (2,2) data x model
         plan = plan_elastic_remesh(2, [1], pod_shape=(2, 2))
         assert plan.mesh_shape == (2, 2)
-        mesh1 = jax.make_mesh(plan.mesh_shape, plan.mesh_axes)
+        mesh1 = make_mesh(plan.mesh_shape, plan.mesh_axes)
         restored = mgr.restore((params, opt))
         assert restored is not None
         (params, opt), step0, _ = restored

@@ -98,7 +98,8 @@ def test_hlo_collective_parser_on_real_module():
     """Parse a real partitioned module with a known all-reduce."""
     if len(jax.devices()) < 1:
         pytest.skip("no devices")
-    mesh = jax.make_mesh((1,), ("x",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("x",))
     from jax.sharding import NamedSharding, PartitionSpec
 
     @jax.jit
@@ -144,3 +145,27 @@ ENTRY %main (a: f32[16]) -> f32[16] {
     # 16 floats * 4 bytes * 12 trips
     np.testing.assert_allclose(stats.bytes_by_kind["all-reduce"],
                                16 * 4 * 12)
+
+
+@pytest.mark.parametrize("env", [None, "given"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env):
+    """The entry points' cache goes to $JAX_COMPILATION_CACHE_DIR when set
+    (left for JAX to read), else to a fixed <checkout>/.jax_cache."""
+    from repro import compile_cache
+    old = jax.config.jax_compilation_cache_dir
+    want = str(tmp_path / "cache") if env else None
+    if env:
+        monkeypatch.setenv(compile_cache.ENV_VAR, want)
+    else:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    try:
+        got = compile_cache.enable()
+        if env:
+            assert got == want
+            assert jax.config.jax_compilation_cache_dir == old
+        else:
+            assert got == str(compile_cache.CHECKOUT / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            assert (compile_cache.CHECKOUT / "src" / "repro").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)

@@ -14,7 +14,6 @@ import dataclasses
 import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro import fault
 from repro.core import distributions as D
@@ -297,6 +296,68 @@ def test_solve_timeout_serves_stale_then_swaps():
     _assert_serving_valid(fr)
 
 
+class _Backend:
+    """Stand-in solver backend: raises ``exc`` or returns NaN tables."""
+
+    def __init__(self, exc=None):
+        self.exc = exc
+
+    def solve_tables_batch(self, Fc, Hc, *args, j_max, t_max, **kw):
+        if self.exc is not None:
+            raise self.exc
+        S = Fc.shape[0]
+        return (np.full((S, j_max + 1, t_max + 1), np.nan, np.float32),
+                np.ones((S, j_max + 1, t_max + 1), np.int32))
+
+
+def test_solver_compile_error_raises_out_of_the_loop(monkeypatch):
+    """A kernel that fails to lower is a fault, not a solve failure: it must
+    raise out of ``run`` instead of degrading to last-good tables."""
+    fr = _runtime()
+    lowering = ValueError("Mosaic failed to compile TPU kernel: cannot "
+                          "statically prove that index in dimension 1 is a "
+                          "multiple of 128")
+    monkeypatch.setattr(C.solver_backends, "get",
+                        lambda name: _Backend(lowering))
+    with pytest.raises(ValueError, match="Mosaic failed to compile"):
+        fr.run(320)
+    assert fr.retries == {"fit": 0, "solve": 0}
+    assert not fr.degraded
+
+
+def test_invalid_tables_retry_then_degrade(monkeypatch):
+    """Tables that fail validation are a solve failure: retried with
+    backoff, then degraded to the last-good tables, never raised."""
+    fr = _runtime()
+    good = fr.live_tables
+    monkeypatch.setattr(C.solver_backends, "get", lambda name: _Backend())
+    rep = fr.run(320)
+    kinds = [k for _, k, _ in rep.events]
+    assert "solve-failure" in kinds and "solve-degraded" in kinds
+    assert rep.retries["solve"] > CFG["max_retries"]
+    assert fr.live_tables is good
+    _assert_serving_valid(fr)
+
+
+@pytest.mark.parametrize("exc, raises", [(ZeroDivisionError("probe"), False),
+                                         (RuntimeError("probe"), True)])
+def test_regret_probe_raises_all_but_numerical_failures(monkeypatch, exc,
+                                                        raises):
+    fr = _runtime(_MATRIX["drift"])
+
+    def probe(**kw):
+        raise exc
+
+    monkeypatch.setattr(fr, "measure_regret", probe)
+    if raises:
+        with pytest.raises(RuntimeError, match="probe"):
+            fr.run(320)
+    else:
+        rep = fr.run(320)
+        cps = [s for s in rep.swaps if s.reason == "change-point"]
+        assert cps and all(s.regret_hours is None for s in cps)
+
+
 def test_stream_regime_switch_is_immediate():
     st = rt.FleetStream(seed=0, block=64)
     st.next()
@@ -321,7 +382,7 @@ def test_mid_sweep_table_swap_rows_bit_identical():
     pre = SC.register(SC.Scenario(name=name,
                                   dist_override=D.Constrained(tau1=1.2)),
                       overwrite=True)
-    with enable_x64():
+    with jax.enable_x64(True):
         tables_pre = C.solve_batch([pre.dist()], 30)
         rows_pre = SC.sweep_checkpointing([name], tables=tables_pre, **kw)
         # the drift refit lands: swap the live dist + tables atomically

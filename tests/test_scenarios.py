@@ -10,7 +10,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import distributions as D
 from repro.core import engine as E
@@ -68,7 +67,7 @@ def test_icdf_newton_accuracy_bounds():
     proper (production-envelope) fit under x64, and degrade gracefully —
     not silently stall — on out-of-envelope saturating fits whose clipped
     CDF plateaus at 1 before L (the documented ~1e-4 worst case)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         for vm_type in D.VM_TYPE_PARAMS:
             d = D.constrained_for(vm_type)
             u = jnp.linspace(1e-9, float(d.cdf(d.L)) - 1e-9, 20001)
@@ -257,7 +256,7 @@ def test_diurnal_cell_engine_matches_reference():
     first, pool = E.draw_lifetime_pool(lf, 200, seed=11)
     ref = C.simulate_makespan(C.dp_policy_fn(tables), lf, job,
                               grid_dt=1.0 / 60.0, pool=pool, first=first)
-    with enable_x64():
+    with jax.enable_x64(True):
         vec = E.simulate_makespan_batch(E.dp_policy_table(tables), job,
                                         first=first, pool=pool,
                                         grid_dt=1.0 / 60.0)

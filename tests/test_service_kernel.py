@@ -11,9 +11,9 @@ grid, pool-exhaustion handling, and ``sweep_service``'s two modes.
 """
 import itertools
 
+import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import distributions as D
 from repro.core import engine as E
@@ -60,7 +60,7 @@ def test_kernel_bit_identical_to_serial_x64(checkpointing):
               cluster_sizes=(2, 3), seeds=(0, 1), n_jobs=8,
               job_hours=2.0, jitter=0.1, pool_size=512,
               checkpointing=checkpointing)
-    with enable_x64():
+    with jax.enable_x64(True):
         rows_s = S.run_bag_grid(mode="serial", **kw)
         rows_b = S.run_bag_grid(mode="batched", **kw)
     _assert_rows_identical(rows_s, rows_b)
@@ -73,7 +73,7 @@ def test_kernel_bit_identical_multi_vm_type_x64():
     kw = dict(vm_types=("n1-highcpu-16", "n1-highcpu-32"),
               policies=("model", "memoryless"), cluster_sizes=(2, 4),
               seeds=(0, 1, 2), n_jobs=8, pool_size=512, checkpointing=True)
-    with enable_x64():
+    with jax.enable_x64(True):
         rows_s = S.run_bag_grid(mode="serial", **kw)
         rows_b = S.run_bag_grid(mode="batched", **kw)
     _assert_rows_identical(rows_s, rows_b)
@@ -85,7 +85,7 @@ def test_sweep_service_modes_agree_x64():
     kw = dict(policies=("model", "memoryless"), cluster_sizes=(2,),
               seeds=(0,), n_jobs=6, pool_size=512)
     scs = SC.default_grid()[:2]
-    with enable_x64():
+    with jax.enable_x64(True):
         rows_s = SC.sweep_service(scs, mode="serial", **kw)
         rows_b = SC.sweep_service(scs, mode="batched", **kw)
     assert rows_b == rows_s
@@ -101,7 +101,7 @@ def test_event_tie_preempt_beats_finish():
     (its seq is older) — the kernel must resolve the tie the same way."""
     lengths = [[1.0]]
     pool = [[1.0, 5.0]]  # first VM dies exactly at segment end
-    with enable_x64():
+    with jax.enable_x64(True):
         res = K.simulate_service_batch(
             lengths=lengths, pools=pool, bag_index=[0], pool_index=[0],
             policy=["memoryless"], cluster_size=[1])
@@ -119,7 +119,7 @@ def test_expire_frees_capacity_for_blocked_jobs():
     expiry must wake the scheduler (serial loop regression, PR 2)."""
     kw = dict(vm_types=("n1-highcpu-32",), policies=("model",),
               cluster_sizes=(1,), seeds=(0, 3), n_jobs=4, pool_size=512)
-    with enable_x64():
+    with jax.enable_x64(True):
         rows_s = S.run_bag_grid(mode="serial", **kw)
         rows_b = S.run_bag_grid(mode="batched", **kw)
     _assert_rows_identical(rows_s, rows_b)
@@ -157,7 +157,7 @@ def test_deflation_absorbs_first_preemption():
     """len-2 job, lifetime 1: the preemption at RO+1 becomes a capacity
     halving — the remaining 1h stretches to 2h, finish at RO+3 exactly, no
     job failure, one fresh lifetime drawn for the survivor."""
-    with enable_x64():
+    with jax.enable_x64(True):
         res = K.simulate_service_batch(
             lengths=[[2.0]], pools=[[1.0, 99.0]], bag_index=[0],
             pool_index=[0], policy=["memoryless"], cluster_size=[1],
@@ -210,7 +210,7 @@ def test_pooled_draw_matches_lazy_stream_x64():
     unchanged: PCG64 uniforms are call-size invariant, and the sampler
     realigns the rng past the external pool before any refill."""
     bag = S._bag_lengths(6, 2.0, 0.1, 0)
-    with enable_x64():
+    with jax.enable_x64(True):
         lazy = S.BatchService(_dist(), cluster_size=3, policy="memoryless",
                               seed=0, pool_size=64).run(bag)
         pool = S.draw_service_pool(_dist(), seed=0, size=64)
@@ -226,7 +226,7 @@ def test_draw_service_pool_batch_matches_serial_pools_x64():
              D.constrained_for("n1-highcpu-32"),
              D.constrained_for("n1-highcpu-16")]
     seeds = [0, 0, 7]
-    with enable_x64():
+    with jax.enable_x64(True):
         mat = K.draw_service_pool_batch(dists, seeds, size=128)
         refs = [S.draw_service_pool(d, seed=s, size=128)
                 for d, s in zip(dists, seeds)]
@@ -306,7 +306,7 @@ def test_service_dollars_bit_identical_to_serial_x64():
     rows_p = np.stack([M.price_trace(M.spot_price_process(), horizon=48.0,
                                      dt=price_dt, seed=7, leaf=i)
                        for i in range(len(cells))])
-    with enable_x64():
+    with jax.enable_x64(True):
         rows_b = K.run_cells_batched(
             cells=cells, dists=[dist], lengths_by_seed=bags,
             reuse_tables=tables, pool_size=512,
@@ -425,7 +425,7 @@ if st is not None:
         """Property: for ANY (bag, cluster mix, policy mix, seed list) the
         batched kernel's rows equal the serial loop's rows under x64."""
         kw = dict(case, pool_size=512)
-        with enable_x64():
+        with jax.enable_x64(True):
             rows_s = S.run_bag_grid(mode="serial", **kw)
             rows_b = S.run_bag_grid(mode="batched", **kw)
         _assert_rows_identical(rows_s, rows_b)

@@ -8,6 +8,7 @@ import sys
 import textwrap
 
 import jax
+import numpy as np
 import pytest
 from jax.sharding import PartitionSpec
 
@@ -77,14 +78,15 @@ def test_pjit_train_step_8_devices():
         from repro import configs, sharding
         from repro.configs.base import TrainConfig, ShapeConfig
         from repro.launch import steps
+        from repro.launch.mesh import make_mesh
         from repro.models import transformer as T
         from repro.optim import adamw_init
 
         cfg = dataclasses.replace(configs.smoke("llama3.2-1b"),
                                   d_model=64, d_ff=128)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         shape = ShapeConfig("t", "train", 32, 4)
-        with mesh, sharding.use(mesh, "fsdp"):
+        with jax.set_mesh(mesh), sharding.use(mesh, "fsdp"):
             in_sh, out_sh, args, _ = steps.shardings_for_cell(
                 cfg, shape, mesh, "fsdp")
             fn = steps.make_train_step(cfg, TrainConfig(warmup_steps=1))
@@ -111,3 +113,47 @@ def test_pjit_train_step_8_devices():
     result = json.loads(out.stdout.strip().splitlines()[-1])
     assert result["devices"] == 8
     assert result["loss"] > 0
+
+
+@pytest.mark.slow
+def test_train_honours_mesh_4_devices(tmp_path):
+    """train(mesh=...) places parameters, optimizer state and batches by the
+    rule table and matches the one-device run's losses."""
+    code = textwrap.dedent(f"""
+        import os
+        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                                   + " --xla_force_host_platform_device_count=4")
+        import dataclasses, json
+        from repro import configs
+        from repro.configs.base import TrainConfig
+        from repro.launch import steps
+        from repro.launch.mesh import make_host_mesh
+        from repro.launch.train import train
+
+        meshes = []
+        cell = steps.shardings_for_cell
+        steps.shardings_for_cell = lambda c, s, mesh, rules: (
+            meshes.append(dict(mesh.shape)) or cell(c, s, mesh, rules))
+        cfg = dataclasses.replace(configs.smoke("smollm-135m"), d_model=64,
+                                  d_ff=128, n_heads=4, n_kv_heads=2)
+        out = {{}}
+        for name, mesh in (("one", None), ("mesh", make_host_mesh())):
+            tc = TrainConfig(ckpt_dir={str(tmp_path)!r} + "/" + name,
+                             ckpt_policy="none", warmup_steps=2,
+                             total_steps=4)
+            out[name] = train(cfg, tc, total_steps=4, seq_len=32,
+                              global_batch=8, mesh=mesh,
+                              verbose=False).losses
+        out["meshes"] = meshes
+        print(json.dumps(out))
+    """)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    assert res["meshes"] == [{"data": 4, "model": 1}]
+    assert len(res["mesh"]) == len(res["one"]) == 4
+    # f32 reductions split over 4 devices reorder sums
+    np.testing.assert_allclose(res["mesh"], res["one"], rtol=1e-4)

@@ -6,7 +6,6 @@ values."""
 import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import distributions as D
 from repro.core import engine as E
@@ -56,7 +55,7 @@ def test_vectorized_executor_exact_vs_reference(dist, tables, start_age,
                                   delta_steps=1, start_age=start_age,
                                   restart_overhead=restart_overhead,
                                   pool=pool, first=first)
-        with enable_x64():
+        with jax.enable_x64(True):
             vec = E.simulate_makespan_batch(
                 table, JOB, first=first, pool=pool, grid_dt=GRID,
                 delta_steps=1, start_age=start_age,

@@ -10,9 +10,9 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import distributions as D
 from repro.core import market as M
@@ -50,7 +50,7 @@ def test_reference_vs_xla_bit_identical_x64(dists):
     dtype, because the solver pins its own f32 arithmetic either way.  (The
     CDF grids themselves are built in session dtype, so the comparison is
     within-session, not across dtypes.)"""
-    with enable_x64():
+    with jax.enable_x64(True):
         ref = C.solve_batch(dists, JOB, grid_dt=GRID, backend="reference")
         xla = C.solve_batch(dists, JOB, grid_dt=GRID, backend="xla")
     assert ref.backend == "reference" and xla.backend == "xla"
@@ -61,7 +61,7 @@ def test_reference_vs_xla_bit_identical_x64(dists):
 def test_refined_verified_tables_bit_identical_x64(dists):
     """Coarse-to-fine with a passing verification is the plain solve: same
     V, same K, to the bit."""
-    with enable_x64():
+    with jax.enable_x64(True):
         plain = C.solve_batch(dists, JOB, grid_dt=GRID)
         ctf = C.solve_batch(dists, JOB, grid_dt=GRID, refine=True,
                             refine_check="full")
@@ -129,7 +129,7 @@ def test_dollar_reference_vs_xla_bit_identical_x64(dists, price):
     set (host-precomputed Pc/Elp grids) through both backends, so per
     scenario slice the batched XLA kernel reproduces the serial reference
     bit-for-bit under an x64 session dtype too."""
-    with enable_x64():
+    with jax.enable_x64(True):
         ref = C.solve_batch(dists, JOB, grid_dt=GRID, restart_overhead=RO,
                             objective="dollars", price=price,
                             backend="reference")
@@ -144,7 +144,7 @@ def test_dollar_reference_vs_xla_bit_identical_x64(dists, price):
 def test_dollar_refined_verified_bit_identical_x64(dists, price):
     """Coarse-to-fine under the dollar objective (the coarse hint solve runs
     dollars too) with a passing full check equals the plain dollar solve."""
-    with enable_x64():
+    with jax.enable_x64(True):
         # refine always runs on the XLA machinery, so compare against an
         # explicit xla plain solve (env-robust under the backend matrix)
         plain = C.solve_batch(dists, JOB, grid_dt=GRID, restart_overhead=RO,
@@ -242,7 +242,7 @@ def test_dollar_sharding_single_device_mesh_transparent(dists, price):
               price=price, backend="xla")
     plain = C.solve_batch(dists, JOB, **kw)
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-    with mesh, sh.use(mesh):
+    with jax.set_mesh(mesh), sh.use(mesh):
         shd = C.solve_batch(dists, JOB, **kw)
         ctf = C.solve_batch(dists, JOB, refine=True,
                             **{**kw, "backend": "auto"})
@@ -323,7 +323,7 @@ def test_sharding_single_device_mesh_transparent(dists, plain):
     from jax.sharding import Mesh
     from repro import sharding as sh
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-    with mesh, sh.use(mesh):
+    with jax.set_mesh(mesh), sh.use(mesh):
         shd = C.solve_batch(dists, JOB, grid_dt=GRID)
         ctf = C.solve_batch(dists, JOB, grid_dt=GRID, refine=True)
     assert np.array_equal(plain.V, shd.V)
@@ -342,10 +342,12 @@ def test_sharding_no_mesh_returns_unwrapped():
 def test_sharding_two_devices_bit_identical():
     """Real shard_map over 2 forced host devices: the sharded S=4 solve
     (plain and refined) must equal the unsharded single-device tables
-    bit-for-bit; an indivisible S=3 falls back transparently."""
+    bit-for-bit; an indivisible S=3 falls back transparently; the CDF grids
+    are built on the host device, not on the caller's mesh."""
     code = textwrap.dedent("""
         import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                                   + " --xla_force_host_platform_device_count=2")
         import json
         import jax
         import numpy as np
@@ -356,12 +358,19 @@ def test_sharding_two_devices_bit_identical():
         dists = [D.Exponential(mttf=8.0), D.Weibull(lam=0.12, k=0.8),
                  D.constrained_for("n1-highcpu-16"), D.Exponential(mttf=16.0)]
         plain = C.solve_batch(dists, 30, grid_dt=1.0 / 6.0, n_sweeps=2)
+        # the CDF grids are built on the host even under the caller's mesh
+        grid_devices = []
+        cdf = D.Exponential.cdf
+        D.Exponential.cdf = lambda self, t: (
+            grid_devices.append(sorted(d.id for d in t.devices()))
+            or cdf(self, t))
         mesh = Mesh(np.array(jax.devices()).reshape(2), ("data",))
-        with mesh, sh.use(mesh):
+        with jax.set_mesh(mesh), sh.use(mesh):
             shd = C.solve_batch(dists, 30, grid_dt=1.0 / 6.0, n_sweeps=2)
             ctf = C.solve_batch(dists, 30, grid_dt=1.0 / 6.0, n_sweeps=2,
                                 refine=True)
             p3 = C.solve_batch(dists[:3], 30, grid_dt=1.0 / 6.0, n_sweeps=2)
+        D.Exponential.cdf = cdf
         u3 = C.solve_batch(dists[:3], 30, grid_dt=1.0 / 6.0, n_sweeps=2)
         print(json.dumps({
             "devices": jax.device_count(),
@@ -370,6 +379,8 @@ def test_sharding_two_devices_bit_identical():
             "refine_eq": bool(np.array_equal(plain.V, ctf.V)),
             "refine_ok": bool(not ctf.refine_info["fallback"]),
             "indivisible_eq": bool(np.array_equal(p3.V, u3.V)),
+            "grids_on_host": bool(grid_devices) and all(
+                ids == [0] for ids in grid_devices),
         }))
     """)
     env = dict(os.environ)
@@ -379,7 +390,8 @@ def test_sharding_two_devices_bit_identical():
     assert out.returncode == 0, out.stderr[-3000:]
     result = json.loads(out.stdout.strip().splitlines()[-1])
     assert result == {"devices": 2, "plain_eq": True, "refine_eq": True,
-                      "refine_ok": True, "indivisible_eq": True}
+                      "refine_ok": True, "indivisible_eq": True,
+                      "grids_on_host": True}
 
 
 # ---------------------------------------------------------------------------
