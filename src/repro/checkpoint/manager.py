@@ -33,6 +33,7 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
+from .. import obs
 from ..core.policies import checkpointing as ckpt_policy
 from ..core.policies import young_daly
 
@@ -120,15 +121,16 @@ def restore_latest(directory: str, template) -> Optional[tuple]:
         return None
     steps = sorted((d for d in os.listdir(directory) if d.startswith("step_")),
                    reverse=True)
-    for d in steps:
-        path = os.path.join(directory, d)
-        manifest = _verify(path)
-        if manifest is None:
-            continue  # torn write (e.g. preempted mid-checkpoint) - skip
-        with np.load(os.path.join(path, "arrays.npz")) as z:
-            flat = {k: z[k] for k in z.files}
-        return (_unflatten_like(template, flat), manifest["step"],
-                manifest["metadata"])
+    with obs.span(obs.CKPT_RESTORE):
+        for d in steps:
+            path = os.path.join(directory, d)
+            manifest = _verify(path)
+            if manifest is None:
+                continue  # torn write (e.g. preempted mid-checkpoint) - skip
+            with np.load(os.path.join(path, "arrays.npz")) as z:
+                flat = {k: z[k] for k in z.files}
+            return (_unflatten_like(template, flat), manifest["step"],
+                    manifest["metadata"])
     return None
 
 
@@ -171,22 +173,24 @@ class CheckpointManager:
         return max(self.grid_dt / max(self.step_time_hours, 1e-9), 1.0)
 
     def _recompute(self):
-        if self.policy == "dp":
-            remaining_h = (self.total_steps - self._last_ckpt_step) \
-                * self.step_time_hours
-            job_steps = max(int(round(remaining_h / self.grid_dt)), 1)
-            # the DP table V/K covers EVERY remaining length j <= job_steps,
-            # so restarts reuse it (the paper: "we precompute the
-            # checkpointing schedule of jobs of different lengths") - only
-            # solve when no table covers the need (e.g. step time grew)
-            if self._tables is None or \
-                    self._tables.V.shape[0] - 1 < job_steps:
-                delta_steps = max(int(round(self.delta_hours / self.grid_dt)),
-                                  1)
-                self._tables = ckpt_policy.solve(
-                    self.dist, job_steps, grid_dt=self.grid_dt,
-                    delta_steps=delta_steps)
-        self._plan_next()
+        with obs.span(obs.CKPT_PLAN):
+            if self.policy == "dp":
+                remaining_h = (self.total_steps - self._last_ckpt_step) \
+                    * self.step_time_hours
+                job_steps = max(int(round(remaining_h / self.grid_dt)), 1)
+                # the DP table V/K covers EVERY remaining length
+                # j <= job_steps, so restarts reuse it (the paper: "we
+                # precompute the checkpointing schedule of jobs of different
+                # lengths") - only solve when no table covers the need (e.g.
+                # step time grew)
+                if self._tables is None or \
+                        self._tables.V.shape[0] - 1 < job_steps:
+                    delta_steps = max(
+                        int(round(self.delta_hours / self.grid_dt)), 1)
+                    self._tables = ckpt_policy.solve(
+                        self.dist, job_steps, grid_dt=self.grid_dt,
+                        delta_steps=delta_steps)
+            self._plan_next()
 
     def _plan_next(self):
         step = self._last_ckpt_step
@@ -223,18 +227,19 @@ class CheckpointManager:
             step >= self._next_ckpt_step
 
     def save(self, step: int, tree, metadata=None, *, emergency: bool = False):
-        self.wait()  # one in-flight write at a time
-        meta = dict(metadata or {})
-        meta["policy"] = self.policy
-        meta["emergency"] = emergency
-        self._writer = save_checkpoint(
-            self.directory, step, tree, meta,
-            blocking=not self.async_write or emergency)
-        self._last_ckpt_step = step
-        self.n_saved += 1
-        if emergency:
-            self.n_emergency += 1
-        self._plan_next()
+        with obs.span(obs.CKPT_SAVE, step=step, emergency=emergency):
+            self.wait()  # one in-flight write at a time
+            meta = dict(metadata or {})
+            meta["policy"] = self.policy
+            meta["emergency"] = emergency
+            self._writer = save_checkpoint(
+                self.directory, step, tree, meta,
+                blocking=not self.async_write or emergency)
+            self._last_ckpt_step = step
+            self.n_saved += 1
+            if emergency:
+                self.n_emergency += 1
+            self._plan_next()
 
     def on_preemption_warning(self, step: int, tree, metadata=None):
         """The provider's 30 s warning: flush an emergency checkpoint NOW."""
@@ -242,8 +247,9 @@ class CheckpointManager:
 
     def wait(self):
         """Block until the in-flight checkpoint write (if any) is on disk."""
-        if self._writer is not None:
-            self._writer.join()
+        if self._writer is not None and self._writer.is_alive():
+            with obs.span(obs.CKPT_WAIT):
+                self._writer.join()
 
     def restore(self, template):
         self.wait()

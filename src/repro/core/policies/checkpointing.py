@@ -69,6 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import obs
 from . import solver_backends
 from .solver_backends import refine as _refine
 from .solver_backends.grids import (  # noqa: F401
@@ -469,21 +470,22 @@ def solve_batch(dists: Sequence, job_steps: int, *, grid_dt: float = 1.0 / 60.0,
         if not np.all(np.isfinite(v_init)):
             raise ValueError("solve_batch(v_init=...): non-finite warm start")
         v_init = jnp.asarray(v_init, jnp.float32)
-    grids_fh = [_cdf_grids(d, grid_dt) for d in dists]
-    Fc = jnp.stack([g[0] for g in grids_fh])
-    Hc = jnp.stack([g[1] for g in grids_fh])
-    # f32-pinned scalars: see _cdf_grids — keeps V/K identical at any dtype
-    gdt, ro = jnp.float32(grid_dt), jnp.float32(restart_overhead)
-    Pc = Elp = None
-    if objective == "dollars":
-        Pc, ro = _dollar_inputs(price, grid_dt, t_max, job_steps,
-                                delta_steps, restart_overhead, len(dists))
-        Elp = jnp.asarray(_dollar_loss_grids(
-            Fc, Hc, Pc, grid_dt, j_max=int(job_steps), t_max=t_max,
-            delta_steps=int(delta_steps)))
+    with obs.span(obs.SOLVE_GRIDS):
+        grids_fh = [_cdf_grids(d, grid_dt) for d in dists]
+        Fc = jnp.stack([g[0] for g in grids_fh])
+        Hc = jnp.stack([g[1] for g in grids_fh])
+        # f32-pinned scalars: see _cdf_grids — keeps V/K identical at any dtype
+        gdt, ro = jnp.float32(grid_dt), jnp.float32(restart_overhead)
+        Pc = Elp = None
+        if objective == "dollars":
+            Pc, ro = _dollar_inputs(price, grid_dt, t_max, job_steps,
+                                    delta_steps, restart_overhead, len(dists))
+            Elp = jnp.asarray(_dollar_loss_grids(
+                Fc, Hc, Pc, grid_dt, j_max=int(job_steps), t_max=t_max,
+                delta_steps=int(delta_steps)))
     statics = dict(j_max=int(job_steps), t_max=t_max,
                    delta_steps=int(delta_steps), n_sweeps=n_sweeps)
-    refine_info = None
+    refine_info = rplan = None
     if refine:
         if backend not in ("auto", "xla"):
             raise ValueError(
@@ -494,18 +496,20 @@ def solve_batch(dists: Sequence, job_steps: int, *, grid_dt: float = 1.0 / 60.0,
                              n_sweeps, refine_factor, refine_radius)
         if rplan is None:
             # grid too small to refine (or single sweep): plain solve
+            refine_info = {"applied": False, "reason": "degenerate"}
+    else:
+        name = solver_backends.resolve(backend)
+    with obs.span(obs.SOLVE_KERNEL, backend=name):
+        if rplan is None:
             V, K = _dispatch_plain(name, Fc, Hc, gdt, ro, v_init, Pc, Elp,
                                    **statics)
-            refine_info = {"applied": False, "reason": "degenerate"}
         else:
             V, K, refine_info = _dispatch_refined(
                 dists, Fc, Hc, grid_dt, gdt, ro, v_init, rplan,
                 refine_check, price, Pc, Elp, **statics)
-    else:
-        name = solver_backends.resolve(backend)
-        V, K = _dispatch_plain(name, Fc, Hc, gdt, ro, v_init, Pc, Elp,
-                               **statics)
-    return BatchDPTables(V=np.asarray(V), K=np.asarray(K), grid_dt=grid_dt,
+    with obs.span(obs.SOLVE_FETCH):
+        V, K = np.asarray(V), np.asarray(K)
+    return BatchDPTables(V=V, K=K, grid_dt=grid_dt,
                          delta_steps=int(delta_steps),
                          restart_overhead=restart_overhead, horizon_idx=t_max,
                          backend=name + ("+refine" if refine else ""),

@@ -57,6 +57,7 @@ from typing import Optional, Sequence
 import jax
 import numpy as np
 
+from .. import obs
 from . import engine, fitting, online
 from . import scenarios as SC
 from . import simulator
@@ -286,9 +287,10 @@ class FleetRuntime:
             raise SolveTimeout(f"solve took {dt:.2f}s "
                                f"(budget {cfg.solve_budget_s}s)")
         try:
-            tab.validate()
-            for s in range(len(tab)):
-                engine.validate_policy_table(tab.K[s])
+            with obs.span(obs.SOLVE_VALIDATE):
+                tab.validate()
+                for s in range(len(tab)):
+                    engine.validate_policy_table(tab.K[s])
         except ValueError as e:
             raise SolveInvalid(str(e)) from e
         self._last_solve_warm = warm
@@ -298,66 +300,68 @@ class FleetRuntime:
     def _try_swap(self, reason: str):
         """Solve + validate + atomically publish; on failure keep last-good
         tables and schedule a bounded backoff retry."""
-        try:
-            tab = self._solve(warm=True)
-        except (SolveTimeout, SolveInvalid) as e:
-            self.retries["solve"] += 1
-            self._solve_attempts += 1
-            self._pending_swap = reason
-            self.events.append((self.obs, "solve-failure", str(e)))
-            if self._solve_attempts <= self.cfg.max_retries:
-                back = self.cfg.retry_backoff_obs * 2 ** (self._solve_attempts - 1)
-                self._next_solve_retry = self.obs + back
-                self.events.append((self.obs, "solve-retry-scheduled",
-                                    f"in {back} obs"))
-            else:
-                # degraded: last-good tables keep serving; the next burst
-                # of attempts waits a full refit period and gets its own
-                # bounded budget (mirrors the fit stage)
-                self.degraded = True
-                self._next_solve_retry = self.obs + self.cfg.refit_every
-                self._solve_attempts = 0
-                self.events.append((self.obs, "solve-degraded",
-                                    "retry budget exhausted; serving "
-                                    "last-good tables"))
-            return
-        # swap: publish tables and the live scenario's dist in one go —
-        # nothing downstream can observe a half-updated pair
-        self._stale_tables = self.live_tables
-        self.live_tables = tab
-        self.live_sc = SC.register(
-            dataclasses.replace(self.live_sc,
-                                dist_override=self.tracker.model),
-            overwrite=True)
-        stale = (self.obs - self._stale_since
-                 if self._stale_since is not None else 0)
-        lag = (self.obs - self._last_drift_injected
-               if self._last_drift_injected is not None else None)
-        regret = None
-        if reason == "change-point":
-            # what the displaced (now-stale) table was costing, measured on
-            # the model the fleet just adapted to; a numerical probe
-            # failure records as None, anything else is a fault and raises
+        with obs.span(obs.SWAP, reason=reason):
             try:
-                regret = self.measure_regret()
-            except ArithmeticError:
-                regret = None
-        self.swaps.append(SwapRecord(
-            obs=self.obs, reason=reason, warm=self._last_solve_warm,
-            solve_seconds=self._last_solve_seconds, stale_obs=stale,
-            lag_from_drift=lag,
-            regret_hours=None if regret is None else regret[0],
-            regret_frac=None if regret is None else regret[1]))
-        if reason == "change-point" and lag is not None \
-                and not self._adaptation_lags:
-            self._adaptation_lags.append(lag)
-        self.events.append((self.obs, "table-swap",
-                            f"{reason}, warm={self._last_solve_warm}, "
-                            f"stale_obs={stale}"))
-        self._stale_since = None
-        self._pending_swap = None
-        self._solve_attempts = 0
-        self.degraded = False
+                tab = self._solve(warm=True)
+            except (SolveTimeout, SolveInvalid) as e:
+                self.retries["solve"] += 1
+                self._solve_attempts += 1
+                self._pending_swap = reason
+                self.events.append((self.obs, "solve-failure", str(e)))
+                if self._solve_attempts <= self.cfg.max_retries:
+                    back = self.cfg.retry_backoff_obs \
+                        * 2 ** (self._solve_attempts - 1)
+                    self._next_solve_retry = self.obs + back
+                    self.events.append((self.obs, "solve-retry-scheduled",
+                                        f"in {back} obs"))
+                else:
+                    # degraded: last-good tables keep serving; the next burst
+                    # of attempts waits a full refit period and gets its own
+                    # bounded budget (mirrors the fit stage)
+                    self.degraded = True
+                    self._next_solve_retry = self.obs + self.cfg.refit_every
+                    self._solve_attempts = 0
+                    self.events.append((self.obs, "solve-degraded",
+                                        "retry budget exhausted; serving "
+                                        "last-good tables"))
+                return
+            # swap: publish tables and the live scenario's dist in one go —
+            # nothing downstream can observe a half-updated pair
+            self._stale_tables = self.live_tables
+            self.live_tables = tab
+            self.live_sc = SC.register(
+                dataclasses.replace(self.live_sc,
+                                    dist_override=self.tracker.model),
+                overwrite=True)
+            stale = (self.obs - self._stale_since
+                     if self._stale_since is not None else 0)
+            lag = (self.obs - self._last_drift_injected
+                   if self._last_drift_injected is not None else None)
+            regret = None
+            if reason == "change-point":
+                # what the displaced (now-stale) table was costing, measured on
+                # the model the fleet just adapted to; a numerical probe
+                # failure records as None, anything else is a fault and raises
+                try:
+                    regret = self.measure_regret()
+                except ArithmeticError:
+                    regret = None
+            self.swaps.append(SwapRecord(
+                obs=self.obs, reason=reason, warm=self._last_solve_warm,
+                solve_seconds=self._last_solve_seconds, stale_obs=stale,
+                lag_from_drift=lag,
+                regret_hours=None if regret is None else regret[0],
+                regret_frac=None if regret is None else regret[1]))
+            if reason == "change-point" and lag is not None \
+                    and not self._adaptation_lags:
+                self._adaptation_lags.append(lag)
+            self.events.append((self.obs, "table-swap",
+                                f"{reason}, warm={self._last_solve_warm}, "
+                                f"stale_obs={stale}"))
+            self._stale_since = None
+            self._pending_swap = None
+            self._solve_attempts = 0
+            self.degraded = False
 
     # -- fit stage ---------------------------------------------------------
     def _on_fit_failure(self, exc: Exception):

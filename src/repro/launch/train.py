@@ -10,8 +10,11 @@ substrate: the loop trains a model on the synthetic pipeline while
     inside the warning window,
   * on pod loss the job restarts on a replacement pod, restores the newest
     intact checkpoint, replays the deterministic data pipeline to the
-    resumed step, and recomputes the DP schedule (the paper's resume rule),
-  * a ``StragglerWatchdog`` demotes slow pods (treated as preemptions).
+    resumed step, and recomputes the DP schedule (the paper's resume rule).
+
+Each step, resume and checkpoint is a named span (``repro.obs``), so a
+profiler trace shows where a job's time goes.  ``fault.StragglerWatchdog``
+is a separate runbook piece; this loop does not demote slow pods.
 
 Simulated time: ``sim_hours_per_step`` maps steps to pod age so a 200-step
 CPU run can traverse hours of the preemption model.  On a real fleet the
@@ -23,17 +26,16 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import time
 
 import jax
 import numpy as np
 
-from .. import compile_cache, configs, sharding
+from .. import compile_cache, configs, obs, sharding
 from ..checkpoint import CheckpointManager
 from ..configs.base import ShapeConfig, TrainConfig
 from ..core import distributions
 from ..data.pipeline import SyntheticLM
-from ..fault import PreemptionSource, StragglerWatchdog
+from ..fault import PreemptionSource
 from ..models import transformer as T
 from ..optim import adamw_init
 from . import steps
@@ -88,7 +90,6 @@ def train(cfg, tc: TrainConfig, *, total_steps: int = 200,
         total_steps=total_steps, async_write=tc.async_checkpoint)
     src = PreemptionSource(dist, n_pods=1, seed=preemption_seed) \
         if inject_preemptions else None
-    dog = StragglerWatchdog()
 
     # resume if a checkpoint exists
     step = 0
@@ -104,17 +105,18 @@ def train(cfg, tc: TrainConfig, *, total_steps: int = 200,
 
     losses = []
     sim_now = 0.0
+    step_span = obs.TRAIN_FIRST_STEP   # traces and compiles (or loads) jitted
     while step < total_steps:
-        t0 = time.time()
-        batch = place(pipe.batch(step), 2)
-        with sharding.use(mesh, rules):   # the model's logical constraints
-            params, opt_state, metrics = jitted(params, opt_state, batch)
-        loss = float(metrics["loss"])
+        with obs.span(step_span, step=step):
+            batch = place(pipe.batch(step), 2)
+            with sharding.use(mesh, rules):   # the model's logical constraints
+                params, opt_state, metrics = jitted(params, opt_state, batch)
+            loss = float(metrics["loss"])
+        step_span = obs.TRAIN_STEP
         losses.append(loss)
         step += 1
         sim_now += sim_hours_per_step
         mgr.observe_step_time(sim_hours_per_step * 3600.0)
-        dog.observe(time.time() - t0)
 
         if verbose and step % log_every == 0:
             print(f"step {step:5d} loss {loss:.4f} "
@@ -130,17 +132,18 @@ def train(cfg, tc: TrainConfig, *, total_steps: int = 200,
                 # 30 s warning: emergency checkpoint, then the pod dies
                 mgr.on_preemption_warning(step, (params, opt_state))
                 # relaunch on a fresh pod + restore + replay pipeline
-                restarts += 1
-                src.replace_pod(0, sim_now)
-                restored = mgr.restore((params, opt_state))
-                assert restored is not None
-                (params, opt_state), ckpt_step, _ = restored
-                params, opt_state = place(params, 0), place(opt_state, 1)
-                preempted_at.append(step)
-                resumed_from.append(ckpt_step)
-                wasted += step - ckpt_step
-                step = ckpt_step
-                mgr.on_restart(pod_age_hours=0.0, resumed_step=step)
+                with obs.span(obs.TRAIN_RESUME, step=step):
+                    restarts += 1
+                    src.replace_pod(0, sim_now)
+                    restored = mgr.restore((params, opt_state))
+                    assert restored is not None
+                    (params, opt_state), ckpt_step, _ = restored
+                    params, opt_state = place(params, 0), place(opt_state, 1)
+                    preempted_at.append(step)
+                    resumed_from.append(ckpt_step)
+                    wasted += step - ckpt_step
+                    step = ckpt_step
+                    mgr.on_restart(pod_age_hours=0.0, resumed_step=step)
                 if verbose:
                     print(f"  !! pod preempted at sim t={sim_now:.2f}h -> "
                           f"restart from step {step}")
