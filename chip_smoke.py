@@ -69,6 +69,7 @@ def phase_solve(res):
     from repro.core import market as M
     from repro.core import scenarios as SC
     from repro.core.policies import checkpointing as C
+    from repro.kernels import dp_recurrence
 
     grid = SC.default_grid()
     dists = [sc.dist() for sc in grid]
@@ -100,10 +101,12 @@ def phase_solve(res):
             excess = ev[0] - ev[1] - PALLAS_TOL["rtol"] * np.abs(ev[1])
             key = f"{objective}/{start}"
             res[key] = dict(backend=tab.backend, seconds=secs,
+                            traces=dp_recurrence.trace_count(),
                             max_abs_diff=float(np.abs(tab.V - ref.V).max()),
                             k_agreement=agree, policy_gap=gap)
             log(f"solve {key}: backend={tab.backend} S={len(grid)} "
-                f"J={J_SOLVE} T={T} {secs:.2f}s max|dV|="
+                f"J={J_SOLVE} T={T} {secs:.2f}s "
+                f"traces={res[key]['traces']} max|dV|="
                 f"{res[key]['max_abs_diff']:.3g} K agreement={agree:.6f} "
                 f"policy gap={gap:.3g}")
             check(float(err.max()) <= PALLAS_TOL["atol"],
@@ -200,6 +203,7 @@ def phase_service(res):
 def phase_loop(res):
     from repro.core import runtime as rt
     from repro.core import scenarios as SC
+    from repro.kernels import dp_recurrence
 
     cfg = rt.RuntimeConfig(
         base_scenarios=tuple(sc.name for sc in SC.default_grid()),
@@ -212,10 +216,12 @@ def phase_loop(res):
     rep = fr.run(400)
     secs = time.perf_counter() - t0
     swaps = [(s.reason, s.warm, s.solve_seconds) for s in rep.swaps]
-    res.update(obs=rep.n_obs, refits=rep.n_refits, swaps=swaps,
+    traces = dp_recurrence.trace_count()
+    res.update(obs=rep.n_obs, refits=rep.n_refits, swaps=swaps, traces=traces,
                retries=rep.retries, degraded=rep.degraded, seconds=secs,
                backend=fr.live_tables.backend)
     log(f"loop: {rep.n_obs} obs {rep.n_refits} refits swaps={swaps} "
+        f"Pallas DP traces={traces} "
         f"retries={rep.retries} degraded={rep.degraded} "
         f"backend={fr.live_tables.backend} {secs:.2f}s")
     check(len(rep.swaps) >= 2, "fewer than two refit-and-swap cycles")

@@ -17,6 +17,7 @@ import sys
 
 from repro import fault
 from repro.core import runtime as rt
+from repro.kernels import dp_recurrence
 
 QUICK = "--quick" in sys.argv
 n_obs = 320 if QUICK else 800
@@ -38,7 +39,8 @@ print("\nevent log (stream -> track -> refit -> re-solve -> swap):")
 for obs, kind, detail in report.events:
     print(f"  obs {obs:4d}: {kind:22s} {detail}")
 
-print(f"\nswaps ({len(report.swaps)}):")
+print(f"\nswaps ({len(report.swaps)}; Pallas DP traces: "
+      f"{dp_recurrence.trace_count()}):")
 for s in report.swaps:
     regret = ("" if s.regret_frac is None
               else f"  stale-K regret {s.regret_hours:+.2f}h "

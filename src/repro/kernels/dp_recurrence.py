@@ -168,25 +168,30 @@ def _dp_kernel(dt_ref, fc_ref, hc_ref, pc_ref, ro_ref, c0_hbm, v_hbm, k_hbm,
     pltpu.sync_copy(k_stage, k_hbm.at[0, pl.ds(s0, SUB), :])
 
 
-def dp_recurrence(Fc, Hc, col0, Ro, grid_dt, *, j_max: int, t_max: int,
-                  delta_steps: int, n_sweeps: int, interpret: bool = False,
-                  Pc=None):
-    """Solve the batched checkpointing DP.
+_traces = 0
 
-    Fc, Hc: (S, t_max+1) f32 CDF / partial-expectation grids (see
-    ``solver_backends.grids``); col0: (S, j_max+1) f32 seed for the
-    restart-cost column (cold ``j*dt`` or a warm start's ``V[:, :, 0]``);
-    Ro: (S,) f32 restart overhead in the objective's unit; grid_dt: the
-    age-grid step in hours (a scalar, traced or not).
-    Returns (V, K) of shapes (S, j_max+1, t_max+1).
 
-    Dollar objective: ``Pc`` is the (S, t_max+1+j_max+delta_steps) f32
-    cumulative-dollar grid.  ``col0`` must be the dollar seed
-    (``Pc[:, :j_max+1]`` cold, or a warm dollar table's column 0).
-    """
+def trace_count() -> int:
+    """How many times the DP call body has been traced in this process.
+
+    The body runs only when a jit cache misses — once per (shapes, dtypes,
+    ``Pc`` structure, statics) for :func:`dp_recurrence` and for the
+    Pallas backend adapter, which traces the same body inside its own
+    compiled call — so this counts exactly the lowerings of the kernel."""
+    return _traces
+
+
+def dp_call(Fc, Hc, col0, Ro, grid_dt, Pc, *, j_max: int, t_max: int,
+            delta_steps: int, n_sweeps: int, interpret: bool):
+    """Traced body of :func:`dp_recurrence`: pads the operands to the
+    kernel's layout, calls the kernel and transposes V and K back.  Callers
+    that trace it inside their own ``jax.jit`` (the Pallas adapter) get one
+    compiled call for their whole body."""
+    global _traces
     S, T = Fc.shape
     if T != t_max + 1:
         raise ValueError(f"grid width {T} != t_max + 1 = {t_max + 1}")
+    _traces += 1
     price = Pc is not None
     Tp, TB = _widths(j_max, t_max, delta_steps)
     S_pad = _round_up(S, SUB)
@@ -227,7 +232,37 @@ def dp_recurrence(Fc, Hc, col0, Ro, grid_dt, *, j_max: int, t_max: int,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=vmem_bytes(j_max, t_max, delta_steps) + (8 << 20)),
         interpret=interpret,
+        name="dp_recurrence",    # the device op's name in a trace
     )(dt, fc, hc, pc, ro, c0)
     # rows were written j-major so each DMA is one aligned (8, Tp) tile run
     return (jnp.transpose(V[:, :S, :T], (1, 0, 2)),
             jnp.transpose(K[:, :S, :T], (1, 0, 2)))
+
+
+_dp_jit = jax.jit(dp_call, static_argnames=(
+    "j_max", "t_max", "delta_steps", "n_sweeps", "interpret"))
+
+
+def dp_recurrence(Fc, Hc, col0, Ro, grid_dt, *, j_max: int, t_max: int,
+                  delta_steps: int, n_sweeps: int, interpret: bool = False,
+                  Pc=None):
+    """Solve the batched checkpointing DP.
+
+    Fc, Hc: (S, t_max+1) f32 CDF / partial-expectation grids (see
+    ``solver_backends.grids``); col0: (S, j_max+1) f32 seed for the
+    restart-cost column (cold ``j*dt`` or a warm start's ``V[:, :, 0]``);
+    Ro: (S,) f32 restart overhead in the objective's unit; grid_dt: the
+    age-grid step in hours (a scalar, traced or not).
+    Returns (V, K) of shapes (S, j_max+1, t_max+1).
+
+    Dollar objective: ``Pc`` is the (S, t_max+1+j_max+delta_steps) f32
+    cumulative-dollar grid.  ``col0`` must be the dollar seed
+    (``Pc[:, :j_max+1]`` cold, or a warm dollar table's column 0).
+
+    One compiled call per (shapes, dtypes, ``Pc`` structure, statics): the
+    pads, the kernel and the transposes are traced and lowered on the first
+    call at a shape (:func:`trace_count`), and later calls only dispatch.
+    """
+    return _dp_jit(Fc, Hc, col0, Ro, grid_dt, Pc, j_max=j_max, t_max=t_max,
+                   delta_steps=delta_steps, n_sweeps=n_sweeps,
+                   interpret=bool(interpret))

@@ -20,6 +20,7 @@ from repro.core import runtime as rt
 from repro.core.policies import checkpointing as C
 from repro.core.policies import solver_backends as SB
 from repro.core.policies.solver_backends import refine as R
+from repro.kernels import dp_recurrence as DP
 
 GRID = 1.0 / 12.0
 JOB = 60
@@ -310,6 +311,86 @@ def test_pallas_warm_start_column_seed(dists):
                          v_init=cold2.V, backend="pallas")
     cold3 = C.solve_batch(dists, job, grid_dt=grid, n_sweeps=3)
     np.testing.assert_allclose(warm.V, cold3.V, rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Pallas backend: one trace and lowering per (shape, statics)
+# ---------------------------------------------------------------------------
+
+def _pallas(dists, job, **kw):
+    return C.solve_batch(dists, job, grid_dt=1.0 / 6.0, n_sweeps=2,
+                         restart_overhead=RO, backend="pallas", **kw)
+
+
+@pytest.mark.pallas
+def test_pallas_same_shape_traces_once(dists):
+    """A second solve at the same shapes, with other distributions, reuses
+    the compiled call: the traced body runs once for both."""
+    job = 11                  # a shape no other test in this file solves
+    n0 = DP.trace_count()
+    _pallas(dists, job)
+    _pallas(dists[::-1], job)
+    assert DP.trace_count() == n0 + 1
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("change", ["job_steps", "cold_after_warm",
+                                    "dollars"])
+def test_pallas_new_shape_or_structure_traces_once(dists, price, change):
+    """Each new shape or operand structure adds exactly one trace, and a
+    repeat of it adds none."""
+    job = {"job_steps": 13, "cold_after_warm": 14, "dollars": 15}[change]
+    warm = dict(v_init=C.solve_batch(dists, job, grid_dt=1.0 / 6.0,
+                                     n_sweeps=1, backend="xla").V)
+    base, (vjob, vkw) = {
+        "job_steps": ({}, (16, {})),
+        "cold_after_warm": (warm, (job, {})),
+        "dollars": ({}, (job, dict(objective="dollars", price=price))),
+    }[change]
+    n0 = DP.trace_count()
+    _pallas(dists, job, **base)
+    assert DP.trace_count() == n0 + 1
+    _pallas(dists, vjob, **vkw)
+    assert DP.trace_count() == n0 + 2
+    _pallas(dists[::-1], vjob, **vkw)
+    assert DP.trace_count() == n0 + 2
+
+
+@pytest.mark.pallas
+def test_pallas_cached_call_matches_fresh_compile(dists):
+    """The cache holds programs, not state: a cache hit's tables are
+    bit-identical to the same inputs solved after ``jax.clear_caches()``."""
+    job = 12
+    _pallas(dists, job)
+    hit = _pallas(dists[::-1], job)
+    n = DP.trace_count()
+    jax.clear_caches()
+    fresh = _pallas(dists[::-1], job)
+    assert DP.trace_count() == n + 1
+    assert np.array_equal(hit.V, fresh.V)
+    assert np.array_equal(hit.K, fresh.K)
+
+
+@pytest.mark.pallas
+def test_pallas_refresh_loop_traces_twice():
+    """The closed loop's refresh (``FleetRuntime._try_swap`` on a new live
+    model, from the same published tables each time) lowers the kernel
+    twice in all: the cold bootstrap and the warm re-solve shape."""
+    from repro.core import scenarios as SC
+    n0 = DP.trace_count()
+    fr = rt.FleetRuntime(rt.RuntimeConfig(
+        base_scenarios=tuple(s.name for s in SC.default_grid()),
+        job_steps=10, grid_dt=0.5, restart_overhead=RO,
+        solver_backend="pallas"))
+    base = fr.live_tables
+    for tau1 in (0.8, 1.0, 1.2, 1.4):
+        fr.live_tables = base
+        fr.tracker.model = D.Constrained(tau1=tau1, tau2=0.8, b=23.9,
+                                         A=0.45, L=24.0)
+        fr._try_swap("initial-fit")
+        assert fr.live_tables is not base and fr._last_solve_warm
+    assert fr.retries["solve"] == 0
+    assert DP.trace_count() == n0 + 2
 
 
 # ---------------------------------------------------------------------------
