@@ -39,15 +39,17 @@ class CellCost:
 
 def _block_linear_flops(cfg: ModelConfig, kind: str) -> float:
     """Forward MAC*2 FLOPs per token in one block's linear layers."""
-    d, hd = cfg.d_model, cfg.head_dim
-    qd, kvd = cfg.q_dim, cfg.kv_dim
+    d = cfg.d_model
     mlp_mats = 2 if cfg.mlp_variant == "gelu" else 3
     if kind in ("attn", "local_attn"):
-        lin = d * qd + 2 * d * kvd + qd * d
+        lin = cfg.attn_params()
         lin += mlp_mats * d * cfg.d_ff
     elif kind == "moe":
-        lin = d * qd + 2 * d * kvd + qd * d
-        lin += d * cfg.n_experts + cfg.top_k * mlp_mats * d * cfg.d_ff
+        # routed: top_k passes of which the held share is computed here
+        passes = cfg.top_k * cfg.n_held / cfg.n_experts \
+            + cfg.n_shared_experts
+        lin = cfg.attn_params()
+        lin += d * cfg.n_experts + passes * 3 * d * cfg.expert_ff
     elif kind == "rglru":
         w = cfg.lru_width
         lin = 2 * d * w + cfg.conv_width * w + w * d
@@ -65,15 +67,16 @@ def _block_linear_flops(cfg: ModelConfig, kind: str) -> float:
 
 def _attn_ctx_flops(cfg: ModelConfig, kind: str, S: int, ctx: int) -> float:
     """Attention/recurrence context FLOPs per SEQUENCE (not per token)."""
-    hd, H = cfg.head_dim, cfg.n_heads
+    H = cfg.n_heads
+    qkv = cfg.qk_head_dim + cfg.value_head_dim     # QK^T + PV widths
     if kind in ("attn", "moe"):
         # causal: ~S*ctx/2 scores when ctx == S; S*ctx when decoding (S=1)
         pairs = S * ctx / 2 if S == ctx else S * ctx
-        return 2.0 * 2.0 * pairs * H * hd          # QK^T + PV
+        return 2.0 * pairs * H * qkv
     if kind == "local_attn":
         w = min(cfg.window or ctx, ctx)
         pairs = S * min(w, ctx) if S == 1 else S * w
-        return 2.0 * 2.0 * pairs * H * hd
+        return 2.0 * pairs * H * qkv
     if kind == "rglru":
         return 8.0 * S * cfg.lru_width              # gates + scan
     if kind == "mlstm":
@@ -88,16 +91,11 @@ def _attn_ctx_flops(cfg: ModelConfig, kind: str, S: int, ctx: int) -> float:
     return 0.0
 
 
-def _layer_kinds(cfg: ModelConfig):
-    period = cfg.block_pattern
-    return [period[i % len(period)] for i in range(cfg.n_layers)]
-
-
 def forward_flops(cfg: ModelConfig, B: int, S: int, ctx: int) -> float:
     """Forward pass FLOPs for B sequences of S new tokens vs ctx context."""
     tok = B * S
     total = 0.0
-    for kind in _layer_kinds(cfg):
+    for kind in cfg.layer_kinds():
         total += tok * _block_linear_flops(cfg, kind)
         total += B * _attn_ctx_flops(cfg, kind, S, ctx)
     total += 2.0 * tok * cfg.d_model * cfg.vocab_size   # lm head
@@ -180,11 +178,12 @@ def cell_cost(cfg: ModelConfig, shape: ShapeConfig, *, chips: int,
 
 def _cache_bytes(cfg: ModelConfig, B: int, S: int, dtype_bytes: int) -> float:
     total = 0.0
-    for kind in _layer_kinds(cfg):
+    kv = cfg.n_kv_heads * (cfg.qk_head_dim + cfg.value_head_dim)
+    for kind in cfg.layer_kinds():
         if kind in ("attn", "moe"):
-            total += 2 * B * S * cfg.kv_dim * dtype_bytes
+            total += B * S * kv * dtype_bytes
         elif kind == "local_attn":
-            total += 2 * B * min(S, cfg.window or S) * cfg.kv_dim * dtype_bytes
+            total += B * min(S, cfg.window or S) * kv * dtype_bytes
         elif kind == "rglru":
             total += B * cfg.lru_width * (4 + (cfg.conv_width - 1) * dtype_bytes)
         elif kind == "mlstm":

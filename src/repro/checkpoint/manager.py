@@ -9,7 +9,10 @@ Mechanics:
   * ``restore_latest`` scans the directory, verifies CRCs, and returns the
     newest intact checkpoint - a half-written checkpoint from a preempted
     pod is skipped, which is exactly the failure mode the paper's 30 s
-    warning window creates.
+    warning window creates;
+  * after each completed write the writer thread keeps the newest ``KEEP``
+    completed checkpoints and deletes older ones, so a long job's saves do
+    not fill the disk (a 16B-class MoE shard's state is ~7 GB a save).
 
 Scheduling: ``CheckpointManager`` consumes the paper's DP policy
 (repro.core.policies.checkpointing).  Given the fitted preemption model, the
@@ -41,6 +44,8 @@ from ..core.policies import young_daly
 # ---------------------------------------------------------------------------
 # serialization
 # ---------------------------------------------------------------------------
+
+KEEP = 2   # completed checkpoints kept after each write
 
 def _flatten(tree) -> dict:
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
@@ -92,12 +97,22 @@ def save_checkpoint(directory: str, step: int, tree, metadata: Optional[dict]
         except BaseException:
             shutil.rmtree(tmp, ignore_errors=True)
             raise
+        prune(directory)
 
     t = threading.Thread(target=write, daemon=True)
     t.start()
     if blocking:
         t.join()
     return t
+
+
+def prune(directory: str, keep: int = KEEP) -> None:
+    """Delete all but the newest ``keep`` completed checkpoints.  A
+    ``step_`` directory is complete once renamed into place (the rename is
+    atomic); half-written ``.tmp_`` directories are not counted."""
+    done = sorted(d for d in os.listdir(directory) if d.startswith("step_"))
+    for d in done[:-keep]:
+        shutil.rmtree(os.path.join(directory, d), ignore_errors=True)
 
 
 def _verify(path: str) -> Optional[dict]:

@@ -22,10 +22,25 @@ class ModelConfig:
     vocab_size: int
     head_dim: int = 0              # 0 -> d_model // n_heads
 
-    # MoE
+    # MoE (dropless): the router scores all ``n_experts``; this device holds
+    # ``experts_held`` = (first, count) of them, (0, 0) meaning all
     n_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    moe_d_ff: int = 0              # expert width (0 -> d_ff); see expert_ff
+    n_shared_experts: int = 0      # shared experts, one SwiGLU of n x moe_d_ff
+    score_fn: str = "softmax"      # softmax | sigmoid (DeepSeek-V3 noaux_tc)
+    norm_topk: bool = True         # renormalise the k chosen weights
+    routed_scale: float = 1.0      # routed_scaling_factor
+    experts_held: Sequence[int] = (0, 0)
+
+    # multi-head latent attention (DeepSeek-V2/V3); 0 -> plain GQA
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # leading dense layers ("attn" blocks, unscanned) before the period
+    first_dense_layers: int = 0
 
     # positions
     pos_type: str = "rope"         # rope | mrope | learned | none
@@ -62,6 +77,7 @@ class ModelConfig:
             object.__setattr__(self, "block_pattern", (kind,))
         object.__setattr__(self, "block_pattern", tuple(self.block_pattern))
         object.__setattr__(self, "mrope_sections", tuple(self.mrope_sections))
+        object.__setattr__(self, "experts_held", tuple(self.experts_held))
         if self.lru_width == 0:
             object.__setattr__(self, "lru_width", self.d_model)
         assert self.n_heads % self.n_kv_heads == 0, "GQA group must divide heads"
@@ -76,14 +92,58 @@ class ModelConfig:
         return self.n_kv_heads * self.head_dim
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Per-head width of queries and keys (MLA: nope + rope slices)."""
+        if self.is_mla:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
+        return self.head_dim
+
+    @property
+    def value_head_dim(self) -> int:
+        return self.v_head_dim if self.is_mla else self.head_dim
+
+    @property
+    def held(self) -> tuple:
+        """(first, count) of the experts this device holds."""
+        first, count = self.experts_held
+        count = count or self.n_experts - first
+        if not (0 <= first and first + count <= self.n_experts):
+            raise ValueError(f"held experts {self.experts_held} outside the "
+                             f"router's {self.n_experts}")
+        return first, count
+
+    @property
+    def n_held(self) -> int:
+        return self.held[1]
+
+    @property
+    def expert_ff(self) -> int:
+        """Width of each routed and shared expert."""
+        return self.moe_d_ff or self.d_ff
+
+    @property
     def n_groups(self) -> int:
         """Number of full pattern periods (scanned)."""
-        return self.n_layers // len(self.block_pattern)
+        return (self.n_layers - self.first_dense_layers) \
+            // len(self.block_pattern)
 
     @property
     def n_tail(self) -> int:
         """Layers after the last full period (executed unscanned)."""
-        return self.n_layers % len(self.block_pattern)
+        return (self.n_layers - self.first_dense_layers) \
+            % len(self.block_pattern)
+
+    def layer_kinds(self) -> list:
+        """Block kind of every layer in order: leading dense layers, then
+        the period repeated (the tail is the period's first blocks)."""
+        period = self.block_pattern
+        return ["attn"] * self.first_dense_layers + [
+            period[i % len(period)]
+            for i in range(self.n_layers - self.first_dense_layers)]
 
     @property
     def is_subquadratic(self) -> bool:
@@ -99,36 +159,43 @@ class ModelConfig:
         if not self.tie_embeddings:
             total += d * v                 # lm head
         total += d                         # final norm
-        per_kind = {}
-        for kind in set(self.block_pattern):
-            per_kind[kind] = self._block_params(kind)
-        for i in range(self.n_layers):
-            total += per_kind[self.block_pattern[i % len(self.block_pattern)]]
+        for kind in self.layer_kinds():
+            total += self._block_params(kind)
         return total
 
     def active_param_count(self) -> int:
-        """Params active per token (MoE: top_k of n_experts)."""
-        if self.family != "moe" or self.n_experts == 0:
+        """Params active per token (MoE: top_k of the held experts' share
+        of the routed experts, i.e. top_k x held / n_experts expert passes)."""
+        if self.n_experts == 0:
             return self.param_count()
+        expert = 3 * self.d_model * self.expert_ff
+        n_moe = self.layer_kinds().count("moe")
+        return self.param_count() - n_moe * self.n_held * expert \
+            + int(n_moe * expert * self.top_k * self.n_held / self.n_experts)
+
+    def attn_params(self) -> int:
         d = self.d_model
-        expert = 3 * d * self.d_ff
-        dense = self.param_count() - self.n_layers * self.n_experts * expert
-        return dense + self.n_layers * self.top_k * expert
+        if self.is_mla:
+            H, r = self.n_heads, self.kv_lora_rank
+            return (d * H * self.qk_head_dim                    # q
+                    + d * (r + self.qk_rope_head_dim) + r       # kv down, norm
+                    + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * d)                  # out
+        return d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
 
     def _block_params(self, kind: str) -> int:
-        d, hd = self.d_model, self.head_dim
-        qd, kvd = self.q_dim, self.kv_dim
+        d = self.d_model
         norm = d
         mlp_mats = 2 if self.mlp_variant == "gelu" else 3
         if kind in ("attn", "local_attn"):
-            attn = d * qd + 2 * d * kvd + qd * d
             mlp = mlp_mats * d * self.d_ff if self.d_ff else 0
-            return attn + mlp + 2 * norm
+            return self.attn_params() + mlp + 2 * norm
         if kind == "moe":
-            attn = d * qd + 2 * d * kvd + qd * d
             router = d * self.n_experts
-            experts = self.n_experts * 3 * d * self.d_ff
-            return attn + router + experts + 2 * norm
+            bias = self.n_experts if self.score_fn == "sigmoid" else 0
+            experts = (self.n_held + self.n_shared_experts) \
+                * 3 * d * self.expert_ff
+            return self.attn_params() + router + bias + experts + 2 * norm
         if kind == "rglru":
             w = self.lru_width
             # in-proj (2 branches) + conv + gate vectors (w_a,b_a,w_i,b_i,lam)
@@ -190,3 +257,7 @@ class TrainConfig:
     step_time_hours: float = 1.0 / 3600.0   # measured online; this is the seed
     vm_type: str = "tpu-v5e-pod"
     async_checkpoint: bool = True
+    # MoE training (DeepSeek-V3): weight of the sequence-wise balance loss,
+    # and the step of the aux-loss-free routing-bias update
+    moe_seq_aux_alpha: float = 1e-4
+    moe_bias_rate: float = 1e-3

@@ -1,5 +1,7 @@
 """phi3.5-moe-42b-a6.6b [moe]: 32L d_model=4096 32H (GQA kv=8) d_ff=6400
-vocab=32064, MoE 16 experts top-2.  [hf:microsoft/Phi-3.5-MoE-instruct]"""
+vocab=32064, MoE 16 experts top-2 (softmax scores, renormalised top-2),
+through the same dropless expert layer as Moonlight.
+[hf:microsoft/Phi-3.5-MoE-instruct]"""
 import dataclasses
 
 from .base import ModelConfig

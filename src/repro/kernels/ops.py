@@ -50,17 +50,18 @@ def _reshape_back(x, B, Sq, H, D=None):
 
 
 def _flash_fwd_shaped(q, k, v, causal, window, scale, block_q, block_k):
-    B, Sq, H, D = q.shape
+    B, Sq, H, _ = q.shape
     out, lse = _flash_fwd_raw(q, k, v, causal, window, scale, block_q, block_k)
-    out = _reshape_back(out, B, Sq, H, D).astype(q.dtype)
+    out = _reshape_back(out, B, Sq, H, v.shape[-1]).astype(q.dtype)
     lse = _reshape_back(lse, B, Sq, H)
     return out, lse
 
 
 def _flash_fwd_raw(q, k, v, causal, window, scale, block_q, block_k):
-    """As _flash_fwd but returns the blocked (nq,B,KV,G,bq,...) layout."""
+    """As _flash_fwd but returns the blocked (nq,B,KV,G,bq,...) layout.
+    q and k share a head width D; v's (Dv) may differ (latent attention)."""
     B, Sq, H, D = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
+    Sk, KV, Dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KV
     bq = _pick_block(Sq, block_q)
     bk = _pick_block(Sk, block_k)
@@ -68,7 +69,7 @@ def _flash_fwd_raw(q, k, v, causal, window, scale, block_q, block_k):
     off = Sk - Sq
     q32 = (q.astype(jnp.float32) * scale).reshape(B, nq, bq, KV, G, D)
     k32 = k.astype(jnp.float32).reshape(B, nk, bk, KV, D)
-    v32 = v.astype(jnp.float32).reshape(B, nk, bk, KV, D)
+    v32 = v.astype(jnp.float32).reshape(B, nk, bk, KV, Dv)
     q_pos = jnp.arange(Sq).reshape(nq, bq) + off
     k_pos = jnp.arange(Sk).reshape(nk, bk)
 
@@ -94,7 +95,7 @@ def _flash_fwd_raw(q, k, v, causal, window, scale, block_q, block_k):
             acc = acc * corr[..., None] + jnp.einsum("bkgqs,bskd->bkgqd", p, vb)
             return (acc, m_new, l), None
 
-        acc0 = jnp.zeros((B, KV, G, bq, D), jnp.float32)
+        acc0 = jnp.zeros((B, KV, G, bq, Dv), jnp.float32)
         m0 = jnp.full((B, KV, G, bq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KV, G, bq), jnp.float32)
         (acc, m, l), _ = jax.lax.scan(kv_step, (acc0, m0, l0), jnp.arange(nk))
@@ -109,7 +110,7 @@ def _flash_bwd(q, k, v, out, lse, dout, causal, window, scale, block_q, block_k)
     """FlashAttention-2 backward: recompute P per block from (q,k,lse); no
     O(S^2) residuals.  All accumulation in f32."""
     B, Sq, H, D = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
+    Sk, KV, Dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KV
     bq = _pick_block(Sq, block_q)
     bk = _pick_block(Sk, block_k)
@@ -117,21 +118,21 @@ def _flash_bwd(q, k, v, out, lse, dout, causal, window, scale, block_q, block_k)
     off = Sk - Sq
     q32 = q.astype(jnp.float32).reshape(B, nq, bq, KV, G, D)
     k32 = k.astype(jnp.float32).reshape(B, nk, bk, KV, D)
-    v32 = v.astype(jnp.float32).reshape(B, nk, bk, KV, D)
-    do32 = dout.astype(jnp.float32).reshape(B, nq, bq, KV, G, D)
-    o32 = out.astype(jnp.float32).reshape(B, nq, bq, KV, G, D)
+    v32 = v.astype(jnp.float32).reshape(B, nk, bk, KV, Dv)
+    do32 = dout.astype(jnp.float32).reshape(B, nq, bq, KV, G, Dv)
+    o32 = out.astype(jnp.float32).reshape(B, nq, bq, KV, G, Dv)
     lse_b = lse.reshape(B, nq, bq, KV, G)
     # delta_i = rowsum(dO_i * O_i), per (nq, bq) block layout
     delta = jnp.einsum("bnqkgd,bnqkgd->bnqkg", do32, o32)    # (B,nq,bq,KV,G)
     q_pos = jnp.arange(Sq).reshape(nq, bq) + off
     k_pos = jnp.arange(Sk).reshape(nk, bk)
 
-    def k_block(ki):
+    def k_block(dq_acc, ki):
         kb, vb = k32[:, ki], v32[:, ki]
         kp = k_pos[ki]
 
         def q_step(carry, qi):
-            dk_acc, dv_acc = carry
+            dk_acc, dv_acc, dq_acc = carry
             qb = q32[:, qi]
             qp = q_pos[qi]
             s = jnp.einsum("bqkgd,bskd->bkgqs", qb * scale, kb)
@@ -146,23 +147,26 @@ def _flash_bwd(q, k, v, out, lse, dout, causal, window, scale, block_q, block_k)
             dp = jnp.einsum("bqkgd,bskd->bkgqs", dob, vb)
             dl = jnp.transpose(delta[:, qi], (0, 2, 3, 1))    # (B,KV,G,bq)
             ds = p * (dp - dl[..., None]) * scale
-            dq_b = jnp.einsum("bkgqs,bskd->bqkgd", ds, kb)
+            dq_acc = dq_acc.at[qi].add(
+                jnp.einsum("bkgqs,bskd->bqkgd", ds, kb))
             dk_acc = dk_acc + jnp.einsum("bkgqs,bqkgd->bskd", ds, qb)
             dv_acc = dv_acc + jnp.einsum("bkgqs,bqkgd->bskd", p, dob)
-            return (dk_acc, dv_acc), dq_b
+            return (dk_acc, dv_acc, dq_acc), None
 
         dk0 = jnp.zeros((B, bk, KV, D), jnp.float32)
-        dv0 = jnp.zeros((B, bk, KV, D), jnp.float32)
-        (dk_b, dv_b), dq_parts = jax.lax.scan(q_step, (dk0, dv0), jnp.arange(nq))
-        return dk_b, dv_b, dq_parts                       # dq_parts: (nq,B,bq,KV,G,D)
+        dv0 = jnp.zeros((B, bk, KV, Dv), jnp.float32)
+        (dk_b, dv_b, dq_acc), _ = jax.lax.scan(q_step, (dk0, dv0, dq_acc),
+                                               jnp.arange(nq))
+        return dq_acc, (dk_b, dv_b)
 
-    dk, dv, dq = jax.lax.map(k_block, jnp.arange(nk))
-    # dq: (nk, nq, B, bq, KV, G, D) -> sum over k blocks
-    dq = dq.sum(axis=0)
+    # dq accumulates over k blocks in one (nq,B,bq,KV,G,D) carry, not one
+    # copy per k block (nk x the size of q: 1.6 GB at S = 8192, 16 x 192)
+    dq0 = jnp.zeros((nq, B, bq, KV, G, D), jnp.float32)
+    dq, (dk, dv) = jax.lax.scan(k_block, dq0, jnp.arange(nk))
     dq = jnp.transpose(dq, (1, 0, 2, 3, 4, 5)).reshape(B, Sq, KV, G, D) \
         .reshape(B, Sq, H, D)
     dk = jnp.moveaxis(dk, 0, 1).reshape(B, Sk, KV, D)
-    dv = jnp.moveaxis(dv, 0, 1).reshape(B, Sk, KV, D)
+    dv = jnp.moveaxis(dv, 0, 1).reshape(B, Sk, KV, Dv)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -198,7 +202,9 @@ flash_attention_xla.defvjp(_fa_fwd, _fa_bwd)
 
 def attention(q, k, v, *, causal: bool = True, window: int = 0,
               scale=None, impl: str = "auto", interpret: bool = True):
-    """Training/prefill attention. q: (B,Sq,H,D); k,v: (B,Sk,KV,D)."""
+    """Training/prefill attention. q: (B,Sq,H,D); k: (B,Sk,KV,D); v:
+    (B,Sk,KV,Dv), Dv may differ from D (``ref`` and ``xla_flash``); the
+    default scale is D**-0.5."""
     if impl == "auto":
         impl = "ref" if k.shape[1] <= _AUTO_FLASH_S else "xla_flash"
     if impl == "ref":
@@ -219,7 +225,7 @@ def _decode_xla(q, k_cache, v_cache, lengths, scale):
     temporaries per layer); f32 only for softmax statistics."""
     from .. import sharding as _shd
     b, h, d = q.shape
-    s, kv = k_cache.shape[1], k_cache.shape[2]
+    s, kv, dv = k_cache.shape[1], k_cache.shape[2], v_cache.shape[-1]
     g = h // kv
     scale = d ** -0.5 if scale is None else scale
     qg = (q.astype(jnp.float32) * scale).astype(k_cache.dtype) \
@@ -232,7 +238,7 @@ def _decode_xla(q, k_cache, v_cache, lengths, scale):
     p = jax.nn.softmax(logits, axis=-1).astype(v_cache.dtype)
     out = jnp.einsum("bkgs,bskd->bkgd", p, v_cache,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, h, d).astype(q.dtype)
+    return out.reshape(b, h, dv).astype(q.dtype)
 
 
 def decode_attention(q, k_cache, v_cache, lengths, *, scale=None,

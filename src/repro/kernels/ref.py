@@ -23,7 +23,8 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
               scale: float | None = None):
     """Multi-head (GQA) attention oracle.
 
-    q: (B, Sq, H, D); k, v: (B, Sk, KV, D); returns (B, Sq, H, D).
+    q: (B, Sq, H, D); k: (B, Sk, KV, D); v: (B, Sk, KV, Dv); returns
+    (B, Sq, H, Dv).
     ``window`` > 0 restricts each query to the last ``window`` keys
     (local/sliding attention); causal offsets assume q occupies the final
     Sq positions of the Sk-long context.
@@ -75,7 +76,7 @@ def decode_attention(q, k_cache, v_cache, lengths, *, scale: float | None = None
     logits = jnp.where(valid, logits, NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgs,bskd->bkgd", p, v32)
-    return out.reshape(b, h, d).astype(q.dtype)
+    return out.reshape(b, h, v_cache.shape[-1]).astype(q.dtype)
 
 
 def linear_recurrence(a, b0, h0=None):

@@ -34,8 +34,13 @@ def abstract_init(cfg: ModelConfig):
     return shapes, box["axes"]
 
 
+def init_opt_state(params):
+    """AdamW's state for the trainable parameters (buffers stay out)."""
+    return adamw_init(T.trainable(params))
+
+
 def abstract_opt_state(param_shapes):
-    return jax.eval_shape(adamw_init, param_shapes)
+    return jax.eval_shape(init_opt_state, param_shapes)
 
 
 def opt_axes(param_axes_tree):
@@ -44,7 +49,8 @@ def opt_axes(param_axes_tree):
     from ..optim.adamw import AdamWState
     is_ax = lambda x: isinstance(x, tuple) and all(
         isinstance(e, (str, type(None))) for e in x)
-    aliased = jax.tree_util.tree_map(sharding.opt_alias, param_axes_tree,
+    aliased = jax.tree_util.tree_map(sharding.opt_alias,
+                                     T.trainable(param_axes_tree),
                                      is_leaf=is_ax)
     return AdamWState(step=(), mu=aliased, nu=aliased)
 
@@ -96,10 +102,24 @@ def input_specs(cfg: ModelConfig, shape: ShapeConfig):
 # step functions
 # ---------------------------------------------------------------------------
 
+# how a microbatched step combines its microbatches' metrics: the MoE counts
+# add up (the whole batch's rows and loads), the largest load is the
+# largest; the loss terms (and anything else) are means
+_MICRO_REDUCE = {"moe_rows": jnp.sum, "moe_routed_held": jnp.sum,
+                 "moe_load": jnp.sum, "moe_load_max": jnp.max}
+
+
 def make_train_step(cfg: ModelConfig, tc: TrainConfig, param_axes=None):
+    """One optimizer step.  AdamW updates the trainable parameters; the MoE
+    routing biases (``T.BUFFERS``) move by their own rule from the step's
+    routing loads, and the sequence-wise balance term enters the loss with
+    weight ``tc.moe_seq_aux_alpha``.  ``metrics`` holds the loss and, for
+    MoE models, ``T.moe_aux``'s counters."""
     accum = max(int(tc.grad_accum), 1)
     is_ax = lambda x: isinstance(x, tuple) and all(
         isinstance(e, (str, type(None))) for e in x)
+    if param_axes is not None:
+        param_axes = T.trainable(param_axes)
 
     def _anchor(tree):
         """Pin a grad-shaped tree to the parameter sharding: without this the
@@ -113,8 +133,13 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, param_axes=None):
             is_leaf=lambda x: is_ax(x))
 
     def train_step(params, opt_state, batch):
+        params, buffers = T.split_buffers(params)
+
         def loss_fn(p, mb):
-            return T.lm_loss(cfg, p, mb)
+            loss, aux = T.lm_loss(cfg, T.merge_buffers(p, buffers), mb)
+            if "moe_balance" in aux:
+                loss = loss + tc.moe_seq_aux_alpha * aux["moe_balance"]
+            return loss, aux
 
         if accum == 1:
             (loss, aux), grads = jax.value_and_grad(
@@ -145,7 +170,9 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, param_axes=None):
             grads, (losses, auxes) = jax.lax.scan(micro, g0, mb)
             grads = jax.tree_util.tree_map(lambda g: g / accum, grads)
             loss = jnp.mean(losses)
-            aux = jax.tree_util.tree_map(jnp.mean, auxes)
+            aux = {k: jax.tree_util.tree_map(functools.partial(
+                _MICRO_REDUCE.get(k, jnp.mean), axis=0), a)
+                for k, a in auxes.items()}
 
         lr = cosine_schedule(opt_state.step, base_lr=tc.learning_rate,
                              warmup_steps=tc.warmup_steps,
@@ -154,8 +181,11 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, param_axes=None):
             grads, opt_state, params, learning_rate=lr, beta1=tc.beta1,
             beta2=tc.beta2, eps=tc.eps, weight_decay=tc.weight_decay,
             grad_clip=tc.grad_clip)
+        loads = aux.pop("moe_load", None)
+        if buffers:
+            buffers = T.update_buffers(buffers, loads, tc.moe_bias_rate)
         metrics = {"loss": loss, **aux, **om}
-        return params, opt_state, metrics
+        return T.merge_buffers(params, buffers), opt_state, metrics
 
     return train_step
 
@@ -208,10 +238,7 @@ def shardings_for_cell(cfg, shape, mesh, rules="baseline"):
         o_sh = sh(opt_axes(p_axes), opt_shapes)
         args = (p_shapes, opt_shapes, specs["batch"])
         in_sh = (p_sh, o_sh, b_sh)
-        metrics_sh = jax.tree_util.tree_map(
-            lambda _: repl, {"loss": 0, "nll": 0, "zloss": 0, "grad_norm": 0,
-                             "lr": 0})
-        out_sh = (p_sh, o_sh, metrics_sh)
+        out_sh = (p_sh, o_sh, repl)       # every metric replicated
         return in_sh, out_sh, args, None
 
     cache_shapes = specs["cache"]

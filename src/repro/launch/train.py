@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 
 import jax
 import numpy as np
@@ -37,8 +38,34 @@ from ..core import distributions
 from ..data.pipeline import SyntheticLM
 from ..fault import PreemptionSource
 from ..models import transformer as T
-from ..optim import adamw_init
 from . import steps
+
+
+# the TrainConfig fields that only this loop reads (the run's seed, its
+# checkpoints and its fleet); the step is built from the rest
+_RUN_FIELDS = ("seed", "ckpt_dir", "ckpt_policy", "ckpt_cost_hours",
+               "step_time_hours", "vm_type", "async_checkpoint")
+
+
+@functools.lru_cache(maxsize=8)
+def _shared_step(builder, cfg, tc, axes_leaves, axes_def):
+    axes = None if axes_def is None else \
+        jax.tree_util.tree_unflatten(axes_def, axes_leaves)
+    return builder(cfg, tc, param_axes=axes)
+
+
+def _train_step(cfg, tc: TrainConfig, param_axes=None):
+    """The step function of (``cfg``, ``tc`` but its run fields,
+    ``param_axes``), one per such triple (and step builder) in the process:
+    ``jax.jit`` of the same function finds the program it compiled and
+    loaded before, where a new function would load a second copy of it on
+    the device (3 GB of reserved temp space for a Moonlight shard)."""
+    tc = dataclasses.replace(tc, **{f: getattr(TrainConfig, f)
+                                    for f in _RUN_FIELDS})
+    leaves, treedef = (None, None) if param_axes is None else \
+        jax.tree_util.tree_flatten(param_axes)
+    return _shared_step(steps.make_train_step, cfg, tc,
+                        None if leaves is None else tuple(leaves), treedef)
 
 
 @dataclasses.dataclass
@@ -71,17 +98,20 @@ def train(cfg, tc: TrainConfig, *, total_steps: int = 200,
     key = jax.random.PRNGKey(tc.seed)
 
     params, axes = T.init(cfg, key)
-    opt_state = adamw_init(params)
+    opt_state = steps.init_opt_state(params)
+    # the optimizer state is updated in place (donated); the parameters are
+    # not, so a caller may keep an earlier step's
     if mesh is None:
         place = lambda tree, i: tree
-        jitted = jax.jit(steps.make_train_step(cfg, tc))
+        jitted = jax.jit(_train_step(cfg, tc), donate_argnums=(1,))
     else:
         in_sh, out_sh, _, _ = steps.shardings_for_cell(
             cfg, ShapeConfig("train", "train", seq_len, global_batch), mesh,
             rules)
         place = lambda tree, i: jax.device_put(tree, in_sh[i])
-        jitted = jax.jit(steps.make_train_step(cfg, tc, param_axes=axes),
-                         in_shardings=in_sh, out_shardings=out_sh)
+        jitted = jax.jit(_train_step(cfg, tc, param_axes=axes),
+                         in_shardings=in_sh, out_shardings=out_sh,
+                         donate_argnums=(1,))
         params, opt_state = place(params, 0), place(opt_state, 1)
 
     mgr = CheckpointManager(

@@ -1,5 +1,5 @@
-"""Shared model layers: norms, projections, rotary embeddings, GQA attention
-blocks, SwiGLU MLP, KV caches.
+"""Shared model layers: norms, projections, rotary embeddings, GQA and
+multi-head latent attention blocks, SwiGLU MLP, KV caches.
 
 Everything is a pure function over explicit parameter pytrees.  Parameters are
 created annotated with logical sharding axes (repro.sharding.P) and stripped
@@ -32,9 +32,9 @@ def pdt(cfg):
 
 # -- norms --------------------------------------------------------------------
 
-def init_rmsnorm(cfg, d=None):
+def init_rmsnorm(cfg, d=None, axis="act_embed"):
     d = d or cfg.d_model
-    return {"scale": A(jnp.ones((d,), pdt(cfg)), "act_embed")}
+    return {"scale": A(jnp.ones((d,), pdt(cfg)), axis)}
 
 
 def rms_norm(x, p, eps):
@@ -110,6 +110,8 @@ def unembed(p_head, p_embed, x, cfg):
 # -- attention block -----------------------------------------------------------
 
 def init_attention(key, cfg):
+    if cfg.is_mla:
+        return init_mla(key, cfg)
     ks = jax.random.split(key, 4)
     d, qd, kvd = cfg.d_model, cfg.q_dim, cfg.kv_dim
     return {
@@ -120,12 +122,34 @@ def init_attention(key, cfg):
     }
 
 
-def init_kv_cache(cfg, batch, max_len, dtype=None):
-    dtype = dtype or jnp.dtype(cfg.compute_dtype)
-    kv, hd = cfg.n_kv_heads, cfg.head_dim
+def init_mla(key, cfg):
+    """Multi-head latent attention (DeepSeek-V2/V3, no query compression):
+    a KV down-projection to the ``kv_lora_rank`` latent plus one RoPE key
+    shared by all heads, an RMSNorm on the latent, and its up-projection to
+    per-head no-RoPE keys and values."""
+    ks = jax.random.split(key, 4)
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
     return {
-        "k": jnp.zeros((batch, max_len, kv, hd), dtype),
-        "v": jnp.zeros((batch, max_len, kv, hd), dtype),
+        "wq": A(_normal(ks[0], (d, H * cfg.qk_head_dim), pdt(cfg)),
+                "w_embed", "w_qdim"),
+        "wkv_a": A(_normal(ks[1], (d, r + cfg.qk_rope_head_dim), pdt(cfg)),
+                   "w_embed", None),
+        "kv_norm": init_rmsnorm(cfg, r, axis=None),
+        "wkv_b": A(_normal(ks[2], (r, H * (cfg.qk_nope_head_dim
+                                           + cfg.v_head_dim)), pdt(cfg)),
+                   None, "w_qdim"),
+        "wo": A(_normal(ks[3], (H * cfg.v_head_dim, d), pdt(cfg)),
+                "w_qdim", "w_embed"),
+    }
+
+
+def init_kv_cache(cfg, batch, max_len, dtype=None):
+    """Per-head keys and values (MLA: the up-projected, non-absorbed form)."""
+    dtype = dtype or jnp.dtype(cfg.compute_dtype)
+    kv = cfg.n_kv_heads
+    return {
+        "k": jnp.zeros((batch, max_len, kv, cfg.qk_head_dim), dtype),
+        "v": jnp.zeros((batch, max_len, kv, cfg.value_head_dim), dtype),
         "pos": jnp.zeros((), jnp.int32),
     }
 
@@ -140,6 +164,40 @@ def _rope_qk(cfg, q, k, positions):
     return q, k
 
 
+def _gqa_qkv(cfg, p, x, positions):
+    B, S, d = x.shape
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    dt = cdt(cfg)
+    q = jnp.einsum("bsd,dq->bsq", x, p["wq"].astype(dt)).reshape(B, S, H, hd)
+    k = jnp.einsum("bsd,dq->bsq", x, p["wk"].astype(dt)).reshape(B, S, KV, hd)
+    v = jnp.einsum("bsd,dq->bsq", x, p["wv"].astype(dt)).reshape(B, S, KV, hd)
+    q, k = _rope_qk(cfg, q, k, positions)
+    return q, k, v
+
+
+def _mla_qkv(cfg, p, x, positions):
+    """Training (non-absorbed) form: keys are [k_nope_h ; k_rope] with the
+    RoPE key shared across heads; RoPE acts on the rope slices only."""
+    B, S, d = x.shape
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    dt = cdt(cfg)
+    q = jnp.einsum("bsd,dq->bsq", x, p["wq"].astype(dt)) \
+        .reshape(B, S, H, nope + rope)
+    kv_a = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"].astype(dt))
+    c = rms_norm(kv_a[..., :r], p["kv_norm"], cfg.norm_eps)
+    k_rope = kv_a[..., r:][:, :, None, :]                   # (B, S, 1, rope)
+    kv = jnp.einsum("bsr,rq->bsq", c, p["wkv_b"].astype(dt)) \
+        .reshape(B, S, H, nope + cfg.v_head_dim)
+    k_nope, v = kv[..., :nope], kv[..., nope:]
+    q_rope = apply_rope(q[..., nope:], positions, cfg.rope_theta)
+    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope, (B, S, H, rope))], axis=-1)
+    return q, k, v
+
+
 def attention_block(cfg, p, x, *, positions, cache=None, mode="train",
                     window=0):
     """x: (B, S, d).  Returns (out, new_cache).
@@ -148,12 +206,8 @@ def attention_block(cfg, p, x, *, positions, cache=None, mode="train",
     decode: S == 1; append to cache (ring buffer when windowed).
     """
     B, S, d = x.shape
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cdt(cfg)
-    q = jnp.einsum("bsd,dq->bsq", x, p["wq"].astype(dt)).reshape(B, S, H, hd)
-    k = jnp.einsum("bsd,dq->bsq", x, p["wk"].astype(dt)).reshape(B, S, KV, hd)
-    v = jnp.einsum("bsd,dq->bsq", x, p["wv"].astype(dt)).reshape(B, S, KV, hd)
-    q, k = _rope_qk(cfg, q, k, positions)
+    q, k, v = (_mla_qkv if cfg.is_mla else _gqa_qkv)(cfg, p, x, positions)
     if mode != "decode":
         # under sequence parallelism the residual stream is seq-sharded, but
         # attention mixes the whole sequence: gather q/k/v ONCE here so the
@@ -215,16 +269,16 @@ def attention_block(cfg, p, x, *, positions, cache=None, mode="train",
                 cache["v"], vv.astype(cache["v"].dtype), (0, 0, 0, 0))
             new_cache = {"k": ck, "v": cv,
                          "pos": jnp.asarray(S, jnp.int32)}
-    out = out.reshape(B, S, H * hd)
+    out = out.reshape(B, S, cfg.n_heads * cfg.value_head_dim)
     out = jnp.einsum("bsq,qd->bsd", out, p["wo"].astype(dt))
     return sharding.constrain(out, "act_batch", "act_seq", "act_embed"), new_cache
 
 
 # -- SwiGLU MLP ----------------------------------------------------------------
 
-def init_mlp(key, cfg):
+def init_mlp(key, cfg, f=None):
     ks = jax.random.split(key, 3)
-    d, f = cfg.d_model, cfg.d_ff
+    d, f = cfg.d_model, f or cfg.d_ff
     p = {
         "gate": A(_normal(ks[0], (d, f), pdt(cfg)), "w_embed", "w_mlp"),
         "down": A(_normal(ks[2], (f, d), pdt(cfg)), "w_mlp", "w_embed"),

@@ -1,8 +1,23 @@
-"""Mixture-of-Experts block: top-k router + capacity-bounded dispatch/combine
-(GShard/Switch style, einsum-based so GSPMD shards experts over the `model`
-mesh axis = expert parallelism).
+"""Mixture-of-Experts block: a router over all experts and a dropless expert
+layer that computes the part of the result given by the experts this device
+holds (``cfg.experts_held``): expert parallelism's local half.  With every
+expert held it is the whole layer; on one chip it runs without the exchange.
 
-Used by moonshot-v1-16b-a3b (64e top-6) and phi3.5-moe-42b-a6.6b (16e top-2).
+Routing, in float32: scores = softmax or sigmoid of x W_r over all E
+experts; the k experts are chosen by score plus the DeepSeek-V3 correction
+bias (``e_score_correction_bias``, sigmoid routers only: used to choose and
+nowhere else); their weights are the unbiased scores, renormalised if
+``norm_topk``, times ``routed_scale``.
+
+Expert layer: the (token, k) pairs whose expert is held are sorted by expert
+into one buffer that holds every such pair (at most T x min(k, held) rows,
+so no capacity and no drops); one grouped matmul computes gate|up, one the
+down projection (``kernels.moe_gmm``), and the weighted rows are
+scatter-added back to their tokens.  Shared experts (one SwiGLU of width
+``n_shared_experts x moe_d_ff``) see every token.
+
+Used by moonshot-v1-16b-a3b (64 experts, 6 per token, sigmoid, 2 shared) and
+phi3.5-moe-42b-a6.6b (16 experts, 2 per token, softmax).
 """
 from __future__ import annotations
 
@@ -10,91 +25,133 @@ import jax
 import jax.numpy as jnp
 
 from .. import sharding
+from ..kernels import moe_gmm
 from ..sharding import annotate as A
 from .layers import cdt, pdt, init_rmsnorm, rms_norm, init_attention, \
-    attention_block, _normal
+    attention_block, init_mlp, mlp_block, _normal
+
+BIAS = "e_score_correction_bias"
 
 
 def init_moe_mlp(key, cfg):
-    ks = jax.random.split(key, 4)
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
-    return {
-        "router": A(_normal(ks[0], (d, e), pdt(cfg)), "w_embed", "w_experts"),
-        "gate": A(_normal(ks[1], (e, d, f), pdt(cfg)), "w_experts",
+    ks = jax.random.split(key, 5)
+    d, f, E, H = cfg.d_model, cfg.expert_ff, cfg.n_experts, cfg.n_held
+    p = {
+        "router": A(_normal(ks[0], (d, E), pdt(cfg)), "w_embed", "w_experts"),
+        "gate": A(_normal(ks[1], (H, d, f), pdt(cfg)), "w_experts",
                   "w_expert_ff", None),
-        "up": A(_normal(ks[2], (e, d, f), pdt(cfg)), "w_experts",
+        "up": A(_normal(ks[2], (H, d, f), pdt(cfg)), "w_experts",
                 "w_expert_ff", None),
-        "down": A(_normal(ks[3], (e, f, d), pdt(cfg)), "w_experts", None,
+        "down": A(_normal(ks[3], (H, f, d), pdt(cfg)), "w_experts", None,
                   "w_expert_ff"),
     }
+    if cfg.score_fn == "sigmoid":
+        p[BIAS] = A(jnp.zeros((E,), jnp.float32), None)
+    if cfg.n_shared_experts:
+        p["shared"] = init_mlp(ks[4], cfg, f=cfg.n_shared_experts * f)
+    return p
 
 
-MOE_GROUP = 512  # tokens per dispatch group (bounds the one-hot tensors)
+def route(cfg, p, xt):
+    """xt: (T, d).  Returns (idx (T, K) chosen experts, w (T, K) float32
+    weights, scores (T, E) float32)."""
+    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    if cfg.score_fn == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif cfg.score_fn == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown score_fn {cfg.score_fn!r}")
+    choose = scores + p[BIAS][None, :] if BIAS in p else scores
+    _, idx = jax.lax.top_k(choose, cfg.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if cfg.norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx, w * cfg.routed_scale, scores
 
 
-def _group_size(T: int) -> int:
-    g = min(MOE_GROUP, T)
-    while T % g:
-        g //= 2
-    return max(g, 1)
+def held_experts(cfg, p, xt, idx, w):
+    """The held experts' part of the routed output, dropless.  Returns
+    ((T, d) float32, per-held-expert row counts (H,) int32)."""
+    T, d = xt.shape
+    K = idx.shape[1]
+    first, H = cfg.held
+    dt = cdt(cfg)
+    e = idx.reshape(-1)
+    key = jnp.where((e >= first) & (e < first + H), e - first, H)
+    order = jnp.argsort(key, stable=True)        # held pairs first, by expert
+    sizes = jnp.sum(key[None, :] == jnp.arange(H)[:, None], axis=1,
+                    dtype=jnp.int32)
+    M = T * min(K, H)                            # every held pair fits
+    tm = moe_gmm.row_tile(M)
+    Mp = -(-M // tm) * tm
+    sel = jnp.pad(order[:M], (0, Mp - M))
+    valid = (jnp.arange(Mp) < jnp.sum(sizes))[:, None]
+    tok = sel // K
+
+    def gmm(x, weights):
+        # rows past the groups come back undefined (NaN, say): select them
+        # away at once, before any product whose gradient would read them
+        return jnp.where(valid, moe_gmm.gmm(x, weights.astype(dt), sizes), 0)
+
+    xs = jnp.where(valid, xt[tok].astype(dt), 0)
+    gu = gmm(xs, jnp.concatenate([p["gate"], p["up"]], axis=-1))
+    f = cfg.expert_ff
+    ys = gmm(jax.nn.silu(gu[:, :f]) * gu[:, f:], p["down"])
+    ys = ys.astype(jnp.float32) * w.reshape(-1)[sel][:, None]
+    return jnp.zeros((T, d), jnp.float32).at[tok].add(ys), sizes
+
+
+def balance(cfg, idx, scores, B):
+    """DeepSeek-V3's sequence-wise balance term (unweighted): per sequence,
+    sum_i f_i P_i with f_i = E/(K S) x the count of tokens choosing expert i
+    and P_i the mean over the sequence of the normalised scores; the mean
+    over sequences."""
+    E, K = cfg.n_experts, cfg.top_k
+    S = idx.shape[0] // B
+    counts = jnp.sum(jax.nn.one_hot(idx.reshape(B, S * K), E,
+                                    dtype=jnp.float32), axis=1)     # (B, E)
+    f = jax.lax.stop_gradient(counts * E / (K * S))
+    s = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    P = jnp.mean(s.reshape(B, S, E), axis=1)
+    return jnp.mean(jnp.sum(f * P, axis=-1))
+
+
+def update_bias(b, load, rate):
+    """The aux-loss-free update: b += rate * sign(mean load - load), over
+    every expert (``load`` may carry leading layer axes)."""
+    return b + rate * jnp.sign(jnp.mean(load, axis=-1, keepdims=True) - load)
 
 
 def moe_mlp(cfg, p, x):
-    """x: (B, S, d) -> (B, S, d) with top-k expert routing.
-
-    GShard-style grouped dense dispatch: tokens are split into groups of
-    ~MOE_GROUP, each group routes into per-expert capacity buffers via
-    one-hot einsums, so everything stays GSPMD-shardable (groups follow the
-    batch/data axis, experts the `model` axis) and the dispatch tensors stay
-    O(group * E * C) instead of O(T * E * C).
-    """
+    """x: (B, S, d) -> ((B, S, d), stats).  stats: ``load`` (E,) pairs routed
+    to each expert, ``rows`` held pairs computed (the grouped matmuls' valid
+    rows), ``routed_held`` pairs routed to held experts (counted from the
+    routing alone: equal to ``rows`` when nothing drops), ``load_max`` the largest
+    held expert's rows over T K / E, and for sigmoid routers ``balance``, the
+    sequence-wise term."""
     B, S, d = x.shape
+    xt = x.reshape(B * S, d)
+    idx, w, scores = route(cfg, p, xt)
+    y, sizes = held_experts(cfg, p, xt, idx, w)
+    y = y.reshape(B, S, d).astype(x.dtype)
+    if cfg.n_shared_experts:
+        y = y + mlp_block(cfg, p["shared"], x)
     E, K = cfg.n_experts, cfg.top_k
-    dt = cdt(cfg)
-    T = B * S
-    Tg = _group_size(T)
-    G = T // Tg
-    xt = x.reshape(G, Tg, d)
-    logits = jnp.einsum("gtd,de->gte", xt,
-                        p["router"].astype(dt)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                     # (G, Tg, E)
-    gate_vals, idx = jax.lax.top_k(probs, K)                    # (G, Tg, K)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-
-    capacity = max(int(cfg.capacity_factor * Tg * K / E), 4)
-    onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)          # (G, Tg, K, E)
-    # position of each (token, k) within its expert's per-group buffer
-    pos = jnp.cumsum(onehot.reshape(G, Tg * K, E), axis=1) \
-        .reshape(G, Tg, K, E) - 1.0
-    keep = (pos < capacity) & (onehot > 0)
-    slot = jnp.where(keep, pos, -1.0).max(-1)                   # (G, Tg, K)
-    pos_oh = jax.nn.one_hot(slot, capacity, dtype=jnp.float32)  # (G, Tg, K, C)
-    disp = jnp.einsum("gtke,gtkc->gtec", onehot * keep, pos_oh)  # (G,Tg,E,C)
-    comb = jnp.einsum("gtec,gtk,gtke->gtec", disp,
-                      gate_vals.astype(jnp.float32), onehot)
-
-    xe = jnp.einsum("gtd,gtec->gecd", xt.astype(jnp.float32),
-                    disp).astype(dt)                            # (G, E, C, d)
-    xe = sharding.constrain(xe, "act_batch", "act_experts", None, None)
-    g = jnp.einsum("gecd,edf->gecf", xe, p["gate"].astype(dt))
-    u = jnp.einsum("gecd,edf->gecf", xe, p["up"].astype(dt))
-    h = jax.nn.silu(g) * u
-    ye = jnp.einsum("gecf,efd->gecd", h, p["down"].astype(dt))
-    ye = sharding.constrain(ye, "act_batch", "act_experts", None, None)
-    yt = jnp.einsum("gecd,gtec->gtd", ye.astype(jnp.float32), comb)
-    y = yt.reshape(B, S, d).astype(x.dtype)
-    return sharding.constrain(y, "act_batch", "act_seq", "act_embed")
-
-
-def aux_load_balance_loss(cfg, x, p):
-    """Switch-style load-balance auxiliary (fraction * router prob per expert)."""
-    dt = cdt(cfg)
-    T = x.shape[0] * x.shape[1]
-    logits = jnp.einsum("bsd,de->bse", x, p["router"].astype(dt))
-    probs = jax.nn.softmax(logits.astype(jnp.float32), -1).reshape(T, -1)
-    top1 = jnp.argmax(probs, -1)
-    frac = jnp.mean(jax.nn.one_hot(top1, cfg.n_experts, dtype=jnp.float32), 0)
-    return cfg.n_experts * jnp.sum(frac * probs.mean(0))
+    first, H = cfg.held
+    load = jnp.sum(jax.nn.one_hot(idx.reshape(-1), E, dtype=jnp.float32),
+                   axis=0)
+    stats = {
+        "load": load,
+        "rows": jnp.sum(sizes),
+        "routed_held": jnp.sum(load[first:first + H]).astype(jnp.int32),
+        "load_max": jnp.max(sizes).astype(jnp.float32) / (B * S * K / E),
+    }
+    if cfg.score_fn == "sigmoid":      # DeepSeek-V3's objective, not softmax's
+        stats["balance"] = balance(cfg, idx, scores, B)
+    return sharding.constrain(y, "act_batch", "act_seq", "act_embed"), stats
 
 
 def init_moe_layer(key, cfg):
@@ -109,5 +166,5 @@ def moe_layer(cfg, p, x, *, positions, cache=None, mode="train", window=0):
                                    positions=positions, cache=cache, mode=mode,
                                    window=window)
     x = x + h
-    x = x + moe_mlp(cfg, p["moe"], rms_norm(x, p["ln2"], cfg.norm_eps))
-    return x, new_cache
+    y, stats = moe_mlp(cfg, p["moe"], rms_norm(x, p["ln2"], cfg.norm_eps))
+    return x + y, new_cache, stats

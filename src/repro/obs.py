@@ -25,6 +25,20 @@ The spans, from the closed loop and the trainer down:
   repro.ckpt.wait        CheckpointManager.wait on a writer in flight
   repro.ckpt.restore     restore_latest: verify and load
   repro.ckpt.plan        CheckpointManager._recompute: the DP schedule
+
+Counters and named device ops beside them, for MoE models:
+
+  moe_rows               the train step's metrics: held (token, expert)
+                         pairs computed, summed over layers (device scalar,
+                         returned with the loss: no host sync of its own)
+  moe_routed_held        the train step's metrics: pairs the router sent to
+                         held experts, summed over layers (moe_rows short
+                         of it counts dropped pairs)
+  moe_load_max           the train step's metrics: the largest held
+                         expert's rows over T K / E, over layers
+  moe_gmm, moe_tgmm      device ops of the expert layer's grouped matmuls
+                         (kernels/moe_gmm.py): forward and input gradient,
+                         weight gradient
 """
 from __future__ import annotations
 

@@ -56,6 +56,28 @@ def test_async_write(tmpdir):
     assert restore_latest(tmpdir, tree)[1] == 3
 
 
+def test_writer_keeps_newest_two(tmpdir):
+    """After each completed write only the newest two checkpoints remain,
+    pruned by the writer thread; a half-written one is neither counted nor
+    removed, and the newest restores."""
+    from repro.checkpoint import manager
+    os.makedirs(tmpdir)
+    torn = os.path.join(tmpdir, ".tmp_torn")
+    os.makedirs(torn)
+    for step in (3, 8, 12, 30):
+        th = save_checkpoint(tmpdir, step, _tree(step), blocking=False)
+        th.join(timeout=60)
+        assert not th.is_alive()
+    kept = sorted(d for d in os.listdir(tmpdir) if d.startswith("step_"))
+    assert manager.KEEP == 2
+    assert kept == ["step_0000000012", "step_0000000030"]
+    assert os.path.isdir(torn)
+    restored, step, _ = restore_latest(tmpdir, _tree())
+    assert step == 30
+    np.testing.assert_array_equal(np.asarray(restored["a"]),
+                                  np.asarray(_tree(30)["a"]))
+
+
 def _mgr(tmpdir, policy, **kw):
     return CheckpointManager(directory=tmpdir, dist=D.constrained_for(),
                              policy=policy, step_time_hours=0.01,

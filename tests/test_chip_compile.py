@@ -98,3 +98,26 @@ def test_service_kernel_compiles_for_v5e(one_chip):
                   price_dt=spec((), f32), max_steps=spec((), i32))
     compiled = SK._service_kernel.lower(lane, shared, slots).compile()
     _assert_fits_hbm(compiled)
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("k, n", [(2048, 2 * 1408), (1408, 2048)])
+def test_moe_gmm_compiles_for_v5e(one_chip, k, n):
+    """The expert layer's grouped matmuls (``moe_gmm`` forward and input
+    gradient, ``moe_tgmm`` weight gradient) at Moonlight's widths: the
+    gate|up and down products of 8 held experts over 8192 x 6 pair rows."""
+    from repro.kernels import moe_gmm
+    m, G = 8192 * 6, 8
+    bf16 = functools.partial(_spec, one_chip, dtype=jnp.bfloat16)
+    sizes = _spec(one_chip, (G,), jnp.int32)
+
+    def fwd_bwd(lhs, rhs, sizes):
+        return jax.grad(lambda a, b: jnp.sum(moe_gmm.gmm(
+            a, b, sizes, impl="pallas").astype(jnp.float32)),
+            argnums=(0, 1))(lhs, rhs)
+
+    compiled = jax.jit(fwd_bwd).lower(bf16((m, k)), bf16((G, k, n)),
+                                      sizes).compile()
+    _assert_fits_hbm(compiled)
+    names = compiled.as_text()
+    assert "moe_gmm" in names and "moe_tgmm" in names

@@ -169,3 +169,25 @@ def test_compile_cache_dir(monkeypatch, tmp_path, env):
             assert (compile_cache.CHECKOUT / "src" / "repro").is_dir()
     finally:
         jax.config.update("jax_compilation_cache_dir", old)
+
+
+def test_train_calls_share_one_step_function():
+    """train() calls that differ only in the run's own fields (seed,
+    checkpoints, fleet) get one step function, so jax.jit finds the program
+    it loaded; any field the step may read, known or new, splits them."""
+    import dataclasses
+    from repro.launch import train as TR
+    cfg = configs.smoke("llama3.2-1b")
+    tc = TrainConfig(warmup_steps=1)
+    run = dataclasses.replace(tc, seed=9, ckpt_dir="elsewhere",
+                              ckpt_policy="none", async_checkpoint=False)
+    assert TR._train_step(cfg, tc) is TR._train_step(cfg, run)
+    for field, value in (("learning_rate", 1e-3), ("grad_accum", 2),
+                         ("moe_bias_rate", 0.0)):
+        other = dataclasses.replace(tc, **{field: value})
+        assert TR._train_step(cfg, other) is not TR._train_step(cfg, tc)
+    from repro.models import transformer as T
+    _, axes = T.init(cfg, jax.random.PRNGKey(0))
+    assert TR._train_step(cfg, tc, param_axes=axes) \
+        is TR._train_step(cfg, run, param_axes=axes) \
+        is not TR._train_step(cfg, tc)
