@@ -6,8 +6,10 @@ Mechanics:
   * writes are atomic (tmp dir + rename) and optionally asynchronous (the
     device->host copy happens synchronously, the disk write on a thread -
     on TPU fleets the same split hides the object-store upload);
-  * ``restore_latest`` scans the directory, verifies CRCs, and returns the
-    newest intact checkpoint - a half-written checkpoint from a preempted
+  * ``restore_latest`` scans the directory and returns the newest intact
+    checkpoint, reading each leaf once from the file straight into the
+    host array it returns and checking its CRC32 there (``restore_bytes``
+    counts the bytes read) - a half-written checkpoint from a preempted
     pod is skipped, which is exactly the failure mode the paper's 30 s
     warning window creates;
   * after each completed write the writer thread keeps the newest ``KEEP``
@@ -25,11 +27,14 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import os
 import shutil
+import struct
 import tempfile
 import threading
 import time
+import zipfile
 import zlib
 from typing import Any, Optional
 
@@ -64,8 +69,9 @@ def _unflatten_like(template, flat: dict):
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
                        for p in path)
         arr = flat[key]
-        assert arr.shape == tuple(leaf.shape), (key, arr.shape, leaf.shape)
-        leaves.append(arr.astype(leaf.dtype))
+        if arr.shape != tuple(leaf.shape):
+            raise ValueError(f"{key}: shape {arr.shape} != {leaf.shape}")
+        leaves.append(arr.astype(leaf.dtype, copy=False))
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
@@ -115,19 +121,75 @@ def prune(directory: str, keep: int = KEEP) -> None:
         shutil.rmtree(os.path.join(directory, d), ignore_errors=True)
 
 
-def _verify(path: str) -> Optional[dict]:
+_LOCAL_HEADER = struct.Struct("<4s22xHH")   # signature, name and extra sizes
+_restore_bytes = 0
+
+
+def restore_bytes() -> int:
+    """Leaf bytes read from checkpoint files into arrays by restores in
+    this process: a restore of one intact checkpoint adds the total size
+    of its manifest's arrays, once."""
+    return _restore_bytes
+
+
+def _read_member(f, info: zipfile.ZipInfo) -> tuple[np.ndarray, int]:
+    """Reads one ``.npy`` member that ``np.savez`` stored uncompressed
+    straight from the open ``.npz`` file into a new array; returns it with
+    the CRC32 of its C-order bytes, computed on that buffer."""
+    global _restore_bytes
+    if info.compress_type != zipfile.ZIP_STORED or info.flag_bits & 1:
+        raise ValueError(f"{info.filename}: not a stored member")
+    f.seek(info.header_offset)
+    raw = f.read(_LOCAL_HEADER.size)
+    if len(raw) != _LOCAL_HEADER.size:
+        raise ValueError(f"{info.filename}: short local header")
+    sig, n_name, n_extra = _LOCAL_HEADER.unpack(raw)
+    if sig != b"PK\x03\x04":
+        raise ValueError(f"{info.filename}: bad local header")
+    start = info.header_offset + _LOCAL_HEADER.size + n_name + n_extra
+    f.seek(start)
+    version = np.lib.format.read_magic(f)
+    if version != (1, 0):   # what np.savez writes for these arrays
+        raise ValueError(f"{info.filename}: .npy version {version}")
     try:
-        with open(os.path.join(path, "manifest.json")) as f:
-            manifest = json.load(f)
-        with np.load(os.path.join(path, "arrays.npz")) as z:
-            for k, info in manifest["arrays"].items():
-                arr = z[k]
-                if zlib.crc32(np.ascontiguousarray(arr).tobytes()) \
-                        != info["crc32"]:
-                    return None
-        return manifest
-    except Exception:
-        return None
+        shape, fortran, dtype = np.lib.format.read_array_header_1_0(f)
+    except Exception as e:  # the header is parsed as a literal: on torn
+        # bytes numpy raises whatever its tokenizer does
+        raise ValueError(f"{info.filename}: bad .npy header") from e
+    if dtype.hasobject:
+        raise ValueError(f"{info.filename}: object array")
+    nbytes = dtype.itemsize * math.prod(shape)
+    if f.tell() - start + nbytes != info.file_size:
+        raise ValueError(f"{info.filename}: header and member size disagree")
+    buf = np.empty(nbytes, np.uint8)
+    if f.readinto(buf) != nbytes:
+        raise ValueError(f"{info.filename}: short read")
+    _restore_bytes += nbytes
+    arr = buf.view(dtype).reshape(shape, order="F" if fortran else "C")
+    # the manifest's CRC is of the C-order bytes, which the file holds
+    # unless the array was saved Fortran-ordered
+    return arr, zlib.crc32(np.ascontiguousarray(arr).tobytes() if fortran
+                           else buf)
+
+
+def _read_checkpoint(path: str, template) -> tuple:
+    """(tree, step, metadata) of the checkpoint in ``path``: one pass over
+    the manifest's arrays, each read once and CRC-checked.  Raises on any
+    fault: a missing or unreadable file or member, a short read, a CRC that
+    disagrees with the manifest, a shape that disagrees with ``template``."""
+    with open(os.path.join(path, "manifest.json")) as f:
+        manifest = json.load(f)
+    flat = {}
+    with open(os.path.join(path, "arrays.npz"), "rb") as f:
+        with zipfile.ZipFile(f) as z:
+            members = {i.filename: i for i in z.infolist()}
+        for key, info in manifest["arrays"].items():
+            arr, crc = _read_member(f, members[key + ".npy"])
+            if crc != info["crc32"]:
+                raise ValueError(f"{key}: CRC32 disagrees with the manifest")
+            flat[key] = arr
+    return (_unflatten_like(template, flat), manifest["step"],
+            manifest["metadata"])
 
 
 def restore_latest(directory: str, template) -> Optional[tuple]:
@@ -138,14 +200,10 @@ def restore_latest(directory: str, template) -> Optional[tuple]:
                    reverse=True)
     with obs.span(obs.CKPT_RESTORE):
         for d in steps:
-            path = os.path.join(directory, d)
-            manifest = _verify(path)
-            if manifest is None:
+            try:
+                return _read_checkpoint(os.path.join(directory, d), template)
+            except (OSError, ValueError, KeyError, zipfile.BadZipFile):
                 continue  # torn write (e.g. preempted mid-checkpoint) - skip
-            with np.load(os.path.join(path, "arrays.npz")) as z:
-                flat = {k: z[k] for k in z.files}
-            return (_unflatten_like(template, flat), manifest["step"],
-                    manifest["metadata"])
     return None
 
 

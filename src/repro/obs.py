@@ -23,7 +23,9 @@ The spans, from the closed loop and the trainer down:
   repro.ckpt.save        CheckpointManager.save (waits, copies, checks,
                          and writes when blocking)
   repro.ckpt.wait        CheckpointManager.wait on a writer in flight
-  repro.ckpt.restore     restore_latest: verify and load
+  repro.ckpt.restore     restore_latest: each leaf read once into its host
+                         array and CRC-checked there (bytes read:
+                         checkpoint.manager.restore_bytes())
   repro.ckpt.plan        CheckpointManager._recompute: the DP schedule
 
 Counters and named device ops beside them, for MoE models:

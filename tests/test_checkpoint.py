@@ -1,4 +1,6 @@
 """Checkpoint manager: roundtrip, torn writes, schedules."""
+import io
+import json
 import os
 import shutil
 
@@ -47,6 +49,102 @@ def test_latest_wins_and_torn_write_skipped(tmpdir):
         f.write(b"\x00" * 64)
     restored, step, _ = restore_latest(tmpdir, t1)
     assert step == 10, "corrupted checkpoint must be skipped"
+
+
+def _member_span(npz: str, arr) -> tuple:
+    """(start, end) of ``arr``'s stored ``.npy`` bytes inside ``npz``."""
+    b = io.BytesIO()
+    np.lib.format.write_array(b, np.asarray(arr))
+    with open(npz, "rb") as f:
+        start = f.read().find(b.getvalue())
+    assert start >= 0
+    return start, start + len(b.getvalue())
+
+
+def _flip_last_leaf_byte(path, tree):
+    npz = os.path.join(path, "arrays.npz")
+    last = jax.tree_util.tree_leaves(tree)[-1]
+    _, end = _member_span(npz, last)
+    with open(npz, "r+b") as f:
+        f.seek(end - 1)
+        byte = f.read(1)
+        f.seek(end - 1)
+        f.write(bytes([byte[0] ^ 0xFF]))
+
+
+def _truncate_mid_member(path, tree):
+    npz = os.path.join(path, "arrays.npz")
+    start, end = _member_span(npz, jax.tree_util.tree_leaves(tree)[0])
+    with open(npz, "r+b") as f:
+        f.truncate((start + end) // 2)
+
+
+def _remove_manifest(path, tree):
+    os.remove(os.path.join(path, "manifest.json"))
+
+
+def _wrong_manifest_crc(path, tree):
+    name = os.path.join(path, "manifest.json")
+    with open(name) as f:
+        manifest = json.load(f)
+    info = next(iter(manifest["arrays"].values()))
+    info["crc32"] = (info["crc32"] + 1) % 2 ** 32
+    with open(name, "w") as f:
+        json.dump(manifest, f)
+
+
+@pytest.mark.parametrize("damage", [_flip_last_leaf_byte,
+                                    _truncate_mid_member, _remove_manifest,
+                                    _wrong_manifest_crc],
+                         ids=["last_leaf_byte", "truncated", "no_manifest",
+                              "manifest_crc"])
+def test_damaged_newest_falls_back_to_older(tmpdir, damage):
+    """Any fault in the newest checkpoint, found while its leaves are read,
+    discards it and restores the next-older one whole."""
+    t1, t2 = _tree(1), _tree(2)
+    save_checkpoint(tmpdir, 10, t1)
+    save_checkpoint(tmpdir, 20, t2)
+    damage(os.path.join(tmpdir, "step_0000000020"), t2)
+    restored, step, _ = restore_latest(tmpdir, t1)
+    assert step == 10
+    for a, b in zip(jax.tree_util.tree_leaves(t1),
+                    jax.tree_util.tree_leaves(restored)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_shape_unlike_template_falls_back_to_older(tmpdir):
+    save_checkpoint(tmpdir, 10, _tree(1))
+    wide = _tree(2)
+    wide["a"] = jnp.zeros((8, 4))
+    save_checkpoint(tmpdir, 20, wide)
+    assert restore_latest(tmpdir, _tree())[1] == 10
+
+
+def test_restore_reads_each_leaf_once_bit_exact(tmpdir):
+    """A restore of an intact checkpoint reads the manifest's array bytes
+    exactly once, and hands back every leaf bit for bit with the
+    template's shape and dtype (int32, a Fortran-ordered and a 0-d leaf
+    among them)."""
+    from repro.checkpoint import manager
+    tree = {"w": jax.random.normal(jax.random.PRNGKey(3), (5, 7)),
+            "i": jnp.arange(12, dtype=jnp.int32).reshape(3, 4),
+            "f": np.asfortranarray(np.arange(6.0).reshape(2, 3)),
+            "s": np.array(-2.5, np.float32)}
+    save_checkpoint(tmpdir, 4, tree)
+    with open(os.path.join(tmpdir, "step_0000000004", "manifest.json")) as f:
+        arrays = json.load(f)["arrays"]
+    total = sum(int(np.prod(a["shape"])) * np.dtype(a["dtype"]).itemsize
+                for a in arrays.values())
+    before = manager.restore_bytes()
+    restored, step, _ = restore_latest(tmpdir, tree)
+    assert step == 4
+    assert manager.restore_bytes() - before == total
+    for a, b in zip(jax.tree_util.tree_leaves(tree),
+                    jax.tree_util.tree_leaves(restored)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert b.dtype == a.dtype and b.shape == a.shape
+        assert np.ascontiguousarray(b).tobytes() == \
+            np.ascontiguousarray(a).tobytes()
 
 
 def test_async_write(tmpdir):
