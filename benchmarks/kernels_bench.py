@@ -61,11 +61,9 @@ def run():
 
 
 def _bench_dp_recurrence():
-    """The checkpointing-DP inner recurrence across the three solver
-    backends on one small workload: the XLA production kernel, the Pallas
-    kernel in interpret mode (CPU emulation — timing is a smoke number, not
-    a device number), and coarse-to-fine on the XLA machinery (see
-    benchmarks/solver_bench.py for the production-scale comparison)."""
+    """The checkpointing-DP inner recurrence on one small workload: the
+    XLA production kernel and the Pallas kernel in interpret mode (CPU
+    emulation — timing is a smoke number, not a device number)."""
     from repro.core import distributions as D
     from repro.core.policies import checkpointing as ckpt
 
@@ -76,12 +74,9 @@ def _bench_dp_recurrence():
     us_x = _bench(lambda: ckpt.solve_batch(dists, job, backend="xla", **wl))
     us_p = _bench(lambda: ckpt.solve_batch(dists, job, backend="pallas",
                                            **wl))
-    us_c = _bench(lambda: ckpt.solve_batch(dists, job, refine=True, **wl))
     emit("kernels/dp_recurrence_xla_S3_J24", us_x, "backend=xla")
     emit("kernels/dp_recurrence_pallas_S3_J24", us_p,
          f"backend=pallas;interpret=True(cpu_smoke);vs_xla={us_x/us_p:.2f}x")
-    emit("kernels/dp_recurrence_ctf_S3_J24", us_c,
-         f"backend=xla+refine;vs_xla={us_x/us_c:.2f}x")
 
 
 if __name__ == "__main__":

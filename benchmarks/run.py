@@ -11,9 +11,9 @@ Usage::
     python -m benchmarks.run [--quick] [--only MODULE[,MODULE...]]
 
 ``--quick`` shrinks the workloads of modules that support it (the
-simulation-engine and scenario-sweep benchmarks) so a full-harness smoke run
-finishes in seconds and still refreshes ``BENCH_simulation.json`` /
-``BENCH_scenarios.json`` at the repo root.
+simulation-engine benchmark among them) so a full-harness smoke run
+finishes in seconds and still refreshes ``BENCH_simulation.json`` at the
+repo root.
 """
 from __future__ import annotations
 
@@ -26,8 +26,7 @@ from repro import compile_cache
 
 from . import (e2e_train, fig1_fit, fig5_wasted_work, fig6_scheduling,
                fig7_checkpointing, fig8_service, kernels_bench, market_bench,
-               runtime_bench, scenario_sweep, service_bench, sim_engine_bench,
-               solver_bench, tonks_lemma)
+               runtime_bench, service_bench, sim_engine_bench, tonks_lemma)
 
 MODULES = [
     ("fig1_fit", fig1_fit),
@@ -37,9 +36,7 @@ MODULES = [
     ("fig8_service", fig8_service),
     ("sim_engine_bench", sim_engine_bench),
     ("service", service_bench),
-    ("scenario_sweep", scenario_sweep),
     ("market", market_bench),
-    ("solver", solver_bench),
     ("runtime", runtime_bench),
     ("tonks_lemma", tonks_lemma),
     ("kernels_bench", kernels_bench),

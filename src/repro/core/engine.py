@@ -497,8 +497,8 @@ def _makespan_kernel_cell(policy_u, tidx, pool_all, pidx, first_steps,
     Vmapped over ``(tidx, pidx, first_steps)`` with the unique-table tensor
     ``(U, j_max+1, t_max+1)`` and the unique-pool tensor ``(Q, n_trials,
     max_restarts+2)`` UNBATCHED, the whole sweep's gathers hit tens of MB
-    instead of the ``B``-times-replicated tensors — the difference between
-    the fold being faster or slower than the grouped dispatch it replaces.
+    instead of the ``B``-times-replicated tensors, so the fold's memory
+    traffic grows with the unique tables and pools, not with the lane count.
     Per lane the lookups return the very same integers/floats, so the
     bit-exactness contract is untouched.
     """

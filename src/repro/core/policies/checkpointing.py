@@ -26,9 +26,8 @@ Faithfulness notes (see DESIGN.md §6):
 The solver dispatches to a pluggable backend package
 (``repro.core.policies.solver_backends``; see ``docs/solver.md``): the
 retained serial reference, the batched XLA kernel, a Pallas VMEM-resident
-kernel (``repro.kernels.dp_recurrence``), and a coarse-to-fine refinement
-pipeline (``refine=True``), optionally ``shard_map``-sharded over the
-``scenario`` logical axis when a ``repro.sharding`` mesh is active.
+kernel (``repro.kernels.dp_recurrence``), optionally ``shard_map``-sharded
+over the ``scenario`` logical axis when a ``repro.sharding`` mesh is active.
 Schedule extraction and the Monte-Carlo executor used by Fig. 7 live below
 the dispatchers.
 
@@ -39,16 +38,16 @@ side is retained forever, and restructuring the production side is only
 legal while these matches hold (enforced by ``tests/test_batched.py`` /
 ``tests/test_sim_engine.py``):
 
-  * :func:`solve_batch` (``backend="xla"``, and the coarse-to-fine pipeline
-    when its verification holds) vs the per-scenario :func:`solve` — V
-    *and* K bit-identical per scenario slice at the solver's native
-    float32, at any session dtype: both build their ``Fc``/``Hc`` grids
-    through one shared helper (:func:`_cdf_grids`) and the batched kernel
-    keeps the reference expression tree (hoisting, column-patching and
-    argmin-restructuring may reorder the schedule, never the per-element
-    arithmetic, so XLA's FMA contraction stays identical).  The Pallas
-    backend is the deliberate exception: it recomputes the probability
-    grids in-kernel and is tolerance-tested instead.
+  * :func:`solve_batch` (``backend="xla"``) vs the per-scenario
+    :func:`solve` — V *and* K bit-identical per scenario slice at the
+    solver's native float32, at any session dtype: both build their
+    ``Fc``/``Hc`` grids through one shared helper (:func:`_cdf_grids`) and
+    the batched kernel keeps the reference expression tree (hoisting,
+    column-patching and argmin-restructuring may reorder the schedule,
+    never the per-element arithmetic, so XLA's FMA contraction stays
+    identical).  The Pallas backend is the deliberate exception: it
+    recomputes the probability grids in-kernel and is tolerance-tested
+    instead.
   * The vectorized executor ``engine.simulate_makespan_batch`` vs
     :func:`simulate_makespan` (the per-trial Python loop kept at the bottom
     of this file) — bit-identical makespans on a shared pre-drawn pool with
@@ -63,7 +62,7 @@ legal while these matches hold (enforced by ``tests/test_batched.py`` /
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Optional, Sequence
+from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -71,7 +70,6 @@ import numpy as np
 
 from ... import obs
 from . import solver_backends
-from .solver_backends import refine as _refine
 from .solver_backends.grids import (  # noqa: F401
     _EPS, cdf_grids as _cdf_grids, dollar_loss_grids as _dollar_loss_grids,
     price_cum_grids as _price_cum_grids)
@@ -121,10 +119,8 @@ class BatchDPTables:
     delta_steps: int
     restart_overhead: float
     horizon_idx: int
-    # provenance (not part of table identity): which backend produced the
-    # tables and, for refine=True, what the refinement pipeline did
+    # provenance (not part of table identity): which backend produced them
     backend: str = "xla"
-    refine_info: Optional[dict] = None
     # unit of V: "makespan" (hours, Eqs. 11-15) or "dollars"
     objective: str = "makespan"
 
@@ -294,112 +290,10 @@ def _dispatch_plain(name: str, Fc, Hc, gdt, ro, v_init, Pc=None, Elp=None, *,
     return fn(*args)
 
 
-def _dispatch_refined(dists, Fc, Hc, grid_dt, gdt, ro, v_init, rplan,
-                      refine_check: str, price=None, Pc=None, Elp=None, *,
-                      j_max: int, t_max: int, delta_steps: int,
-                      n_sweeps: int):
-    """The coarse-to-fine pipeline (see ``solver_backends.refine``): coarse
-    hint solve at ``factor x grid_dt``, a host round-trip turning its argmin
-    table into static per-segment candidate caps, pruned pre-sweeps, one
-    full-resolution sweep — falling back to the plain XLA solve whenever the
-    column-0 check (or the optional full check) fails.
-
-    Dollar mode (``Pc``/``price`` given): the coarse hint solve runs the
-    dollar objective too — a makespan hint would point at the wrong argmin
-    in priced windows — on a coarse cumulative-dollar grid built from the
-    same ``price``.  The dollar restart overhead ``ro`` is shared between
-    levels (same launch cell at either resolution)."""
-    statics = dict(j_max=j_max, t_max=t_max, delta_steps=delta_steps,
-                   n_sweeps=n_sweeps)
-    factor, radius = rplan["factor"], rplan["radius"]
-    j_max_c, delta_c = rplan["j_max_c"], rplan["delta_steps_c"]
-    Fcs_c, Hcs_c, t_max_c = [], [], None
-    for d in dists:
-        f, h, t_max_c = _cdf_grids(d, grid_dt * factor)
-        Fcs_c.append(f)
-        Hcs_c.append(h)
-    Fc_c, Hc_c = jnp.stack(Fcs_c), jnp.stack(Hcs_c)
-    S = Fc.shape[0]
-
-    if Pc is None:
-        coarse = lambda fc, hc: (_refine.coarse_tables(
-            fc, hc, jnp.float32(grid_dt * factor), ro, j_max_c=j_max_c,
-            t_max_c=t_max_c, delta_steps_c=delta_c, n_sweeps=n_sweeps),)
-        cargs = (Fc_c, Hc_c)
-    else:
-        Pc_c, _ = _dollar_inputs(price, grid_dt * factor, t_max_c, j_max_c,
-                                 delta_c, 0.0, S)
-        Elp_c = jnp.asarray(_dollar_loss_grids(
-            Fc_c, Hc_c, Pc_c, grid_dt * factor, j_max=j_max_c,
-            t_max=t_max_c, delta_steps=delta_c))
-        coarse = lambda fc, hc, pcc, epc, rv: (_refine.coarse_tables(
-            fc, hc, jnp.float32(grid_dt * factor), rv, j_max_c=j_max_c,
-            t_max_c=t_max_c, delta_steps_c=delta_c, n_sweeps=n_sweeps,
-            Pc_c=pcc, Elp_c=epc),)
-        cargs = (Fc_c, Hc_c, Pc_c, Elp_c, ro)
-    fn_c, _ = solver_backends.shard_scenarios(coarse, S, len(cargs), 1)
-    (Kc,) = fn_c(*cargs)
-
-    # host round-trip: the coarse argmin becomes STATIC candidate caps (the
-    # bit-safe prefix-slice form of "refine near the argmin"); retraces are
-    # cached per cap tuple, which a sweep over one workload reuses
-    cone_segs = _refine.cone_segments(j_max, t_max, delta_steps)
-    caps = _refine.candidate_caps(Kc, cone_segs, factor=factor,
-                                  radius=radius, j_max_c=j_max_c,
-                                  t_max_c=t_max_c)
-
-    rstatics = dict(statics, caps=caps)
-    c0 = None if v_init is None else v_init[:, :, 0]
-    if Pc is None:
-        if c0 is None:
-            kern = lambda fc, hc: _refine.refined_solve(
-                fc, hc, gdt, ro, None, **rstatics)
-            args = (Fc, Hc)
-        else:
-            kern = lambda fc, hc, c0: _refine.refined_solve(
-                fc, hc, gdt, ro, c0, **rstatics)
-            args = (Fc, Hc, c0)
-    else:
-        if c0 is None:
-            kern = lambda fc, hc, pc, ep, rv: _refine.refined_solve(
-                fc, hc, gdt, rv, None, pc, ep, **rstatics)
-            args = (Fc, Hc, Pc, Elp, ro)
-        else:
-            kern = lambda fc, hc, c0, pc, ep, rv: _refine.refined_solve(
-                fc, hc, gdt, rv, c0, pc, ep, **rstatics)
-            args = (Fc, Hc, c0, Pc, Elp, ro)
-    fn, _ = solver_backends.shard_scenarios(kern, S, len(args), 3)
-    V, K, ok = fn(*args)
-
-    info = dict(rplan, applied=True, t_max_c=t_max_c, caps=list(caps),
-                verified_col0=bool(np.asarray(ok).all()), fallback=False)
-    if not info["verified_col0"]:
-        # a cap cut off an argmin on the restart-cost chain: the refined
-        # tables are not trustworthy — serve the plain solve instead
-        V, K = _dispatch_plain("xla", Fc, Hc, gdt, ro, v_init, Pc, Elp,
-                               **statics)
-        info["fallback"] = True
-        return V, K, info
-    if refine_check == "full":
-        # debug/CI harness: compare the whole refined table against the
-        # plain solve (costs more than the solve it checks)
-        Vf, Kf = _dispatch_plain("xla", Fc, Hc, gdt, ro, v_init, Pc, Elp,
-                                 **statics)
-        match = bool(np.array_equal(np.asarray(V), np.asarray(Vf))
-                     and np.array_equal(np.asarray(K), np.asarray(Kf)))
-        info["full_check_match"] = match
-        if not match:
-            V, K = Vf, Kf
-            info["fallback"] = True
-    return V, K, info
-
-
 def solve_batch(dists: Sequence, job_steps: int, *, grid_dt: float = 1.0 / 60.0,
                 delta_steps: int = 1, n_sweeps: int = 3,
                 restart_overhead: float = 0.0, v_init=None,
-                backend: str = "auto", refine: bool = False,
-                refine_factor: int = 4, refine_radius: Optional[int] = None,
-                refine_check: str = "col0", objective: str = "makespan",
+                backend: str = "auto", objective: str = "makespan",
                 price=None) -> BatchDPTables:
     """Solve the checkpointing DP for a whole scenario batch in ONE compiled
     call (see ``solver_backends`` and ``docs/solver.md``).
@@ -413,15 +307,6 @@ def solve_batch(dists: Sequence, job_steps: int, *, grid_dt: float = 1.0 / 60.0,
 
     ``backend`` selects the kernel (``"auto"``/``"reference"``/``"xla"``/
     ``"pallas"``; ``"auto"`` honors the ``REPRO_SOLVER_BACKEND`` env var).
-    ``refine=True`` runs the coarse-to-fine pipeline on the XLA machinery:
-    a coarse solve at ``refine_factor x grid_dt`` supplies argmin hints that
-    cap the pre-sweeps' candidate axis (to ``factor*K_c + refine_radius``
-    per segment) inside the column-0 dependency cone, and the final sweep
-    runs at full resolution;
-    a bit-level column-0 verification guards every pre-sweep, falling back
-    to the plain solve on failure (``refine_check="full"`` additionally
-    compares the whole table in-process; ``"off"`` is not available — the
-    column check is always on).
 
     ``v_init`` optionally warm-starts the restart-cost fixed point from a
     previous solve's ``V`` array of matching shape ``(S, j_max+1, t_max+1)``
@@ -447,8 +332,8 @@ def solve_batch(dists: Sequence, job_steps: int, *, grid_dt: float = 1.0 / 60.0,
     exactly where the price makes lost work cheap or checkpoint overhead
     expensive.  On a flat grid at p $/h every cost term is p x the makespan
     term, so V reduces to ``p x V_makespan`` (up to float32 rounding; the
-    property tests pin this).  All backends, warm starts, ``refine=True``
-    and scenario sharding work identically under either objective, and the
+    property tests pin this).  All backends, warm starts and scenario
+    sharding work identically under either objective, and the
     reference<->xla bit-identity contract covers both.
     """
     _check_objective(objective, price)
@@ -485,35 +370,16 @@ def solve_batch(dists: Sequence, job_steps: int, *, grid_dt: float = 1.0 / 60.0,
                 delta_steps=int(delta_steps)))
     statics = dict(j_max=int(job_steps), t_max=t_max,
                    delta_steps=int(delta_steps), n_sweeps=n_sweeps)
-    refine_info = rplan = None
-    if refine:
-        if backend not in ("auto", "xla"):
-            raise ValueError(
-                f"solve_batch(refine=True) runs on the XLA machinery; "
-                f"backend={backend!r} is contradictory")
-        name = "xla"
-        rplan = _refine.plan(int(job_steps), t_max, int(delta_steps),
-                             n_sweeps, refine_factor, refine_radius)
-        if rplan is None:
-            # grid too small to refine (or single sweep): plain solve
-            refine_info = {"applied": False, "reason": "degenerate"}
-    else:
-        name = solver_backends.resolve(backend)
+    name = solver_backends.resolve(backend)
     with obs.span(obs.SOLVE_KERNEL, backend=name):
-        if rplan is None:
-            V, K = _dispatch_plain(name, Fc, Hc, gdt, ro, v_init, Pc, Elp,
-                                   **statics)
-        else:
-            V, K, refine_info = _dispatch_refined(
-                dists, Fc, Hc, grid_dt, gdt, ro, v_init, rplan,
-                refine_check, price, Pc, Elp, **statics)
+        V, K = _dispatch_plain(name, Fc, Hc, gdt, ro, v_init, Pc, Elp,
+                               **statics)
     with obs.span(obs.SOLVE_FETCH):
         V, K = np.asarray(V), np.asarray(K)
     return BatchDPTables(V=V, K=K, grid_dt=grid_dt,
                          delta_steps=int(delta_steps),
                          restart_overhead=restart_overhead, horizon_idx=t_max,
-                         backend=name + ("+refine" if refine else ""),
-                         refine_info=refine_info, objective=objective)
+                         backend=name, objective=objective)
 
 
 def extract_schedule(tables: DPTables, job_steps: int,

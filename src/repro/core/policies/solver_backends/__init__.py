@@ -24,10 +24,6 @@ Backends:
   pallas     ``repro.kernels.dp_recurrence`` — VMEM-resident blocked scan;
              tolerance-tested, interpret mode off-TPU.
 
-plus ``refine`` (coarse-to-fine pruning around the coarse argmin), which is
-an orchestration over the ``xla`` machinery rather than a fourth contract
-implementation — ``checkpointing.solve_batch(refine=True)`` drives it.
-
 Selection: an explicit ``backend=`` name always wins; ``"auto"`` consults
 the ``REPRO_SOLVER_BACKEND`` env var and otherwise picks Pallas on TPU and
 XLA everywhere else.
@@ -47,7 +43,7 @@ import jax
 from jax.sharding import PartitionSpec
 
 from .... import sharding as _sharding
-from . import grids, reference, refine, xla
+from . import grids, reference, xla
 from . import pallas as pallas_backend
 
 BACKENDS = ("reference", "xla", "pallas")

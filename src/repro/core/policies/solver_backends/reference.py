@@ -2,7 +2,7 @@
 
 This is the original per-scenario ``_solve_tables`` kernel, retained forever
 per the contract in ``checkpointing.py``: every production backend (XLA,
-Pallas, coarse-to-fine) is measured against the tables this kernel produces.
+Pallas) is measured against the tables this kernel produces.
 It is deliberately unclever — the (age x candidate) grids are recomputed in
 every j iteration and the batch path is a plain Python loop over scenarios —
 because its job is to be obviously faithful to Eqs. 11-15, not fast.

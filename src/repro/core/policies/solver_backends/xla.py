@@ -1,11 +1,9 @@
 """Batched XLA DP kernel — the CPU/GPU production backend.
 
-This is the PR-3/PR-4 ``_solve_tables_batch`` kernel moved out of
-``checkpointing.py`` and factored into reusable pieces (``candidate_grids``,
-``seg_plan``, ``seg_views``, ``sweep_from_R``) so the coarse-to-fine
-refinement backend (``refine.py``) can compose the *same* expression tree:
-the hoisted grids and the full-resolution sweep it runs are these functions,
-not copies, which is what keeps the refined tables bit-comparable.
+The kernel is built from module-private pieces: ``_candidate_grids``
+hoists the j-invariant grids, ``_seg_plan``/``_seg_views`` split the j loop
+into segments over column prefixes of those grids, ``_body_factory`` is the
+one-row update and ``_sweep_from_R`` one full sweep.
 
 Per scenario slice the solve is BIT-IDENTICAL to the serial reference kernel
 (``reference.solve_tables``) — the per-candidate arithmetic keeps the
@@ -40,7 +38,7 @@ import jax.numpy as jnp
 from .grids import _EPS
 
 
-def seg_plan(j_max: int):
+def _seg_plan(j_max: int):
     """The j-axis segmentation: thirds of the remaining-work axis when wide
     enough to keep every segment SIMD-wide (a very narrow cost matrix
     compiles to different, ULP-shifting, scalar codegen)."""
@@ -52,7 +50,7 @@ def seg_plan(j_max: int):
     return [(j_max, 1, j_max + 1)]
 
 
-def candidate_grids(Fc, Hc, dt, *, j_max, t_max, delta_steps, Pc=None,
+def _candidate_grids(Fc, Hc, dt, *, j_max, t_max, delta_steps, Pc=None,
                     Elp=None):
     """Hoist the j-invariant (VM age x candidate) grids, vmapped over the
     scenario axis.  Identical per-element arithmetic to the reference body.
@@ -98,7 +96,7 @@ def candidate_grids(Fc, Hc, dt, *, j_max, t_max, delta_steps, Pc=None,
     return base + (dp_nf_f, Elp[:, 0], dp_fd_f, Elp[:, 1])
 
 
-def seg_views(gp, delta_steps, I_len):
+def _seg_views(gp, delta_steps, I_len):
     """A shorter candidate axis is a column prefix of the full grids (column
     i's values depend only on i), so segments share one precomputed set;
     end grids are parameter-independent (one copy)."""
@@ -114,7 +112,7 @@ def seg_views(gp, delta_steps, I_len):
     return sd
 
 
-def body_factory(sd, R, dead, dt, j_max):
+def _body_factory(sd, R, dead, dt, j_max):
     """One j-row update over a segment's candidate prefix (see module
     docstring for the restructurings vs the reference body)."""
     dollar = len(sd) > 8
@@ -184,7 +182,7 @@ def body_factory(sd, R, dead, dt, j_max):
     return body
 
 
-def sweep_from_R(gp, seg_data, segs, R, dead, dt, *, j_max, t_max):
+def _sweep_from_R(gp, seg_data, segs, R, dead, dt, *, j_max, t_max):
     """One full-resolution DP sweep from a given restart-cost vector
     ``R`` of shape ``(S, j_max+1)``.  Returns fresh ``(V, K)``."""
     S = R.shape[0]
@@ -192,8 +190,8 @@ def sweep_from_R(gp, seg_data, segs, R, dead, dt, *, j_max, t_max):
     K0 = jnp.zeros((S, j_max + 1, t_max + 1), jnp.int32)
     VK = (V0, K0)
     for sd, (_, lo, hi) in zip(seg_data, segs):
-        VK = jax.lax.fori_loop(lo, hi, body_factory(sd, R, dead, dt, j_max),
-                               VK)
+        VK = jax.lax.fori_loop(lo, hi,
+                               _body_factory(sd, R, dead, dt, j_max), VK)
     return VK
 
 
@@ -204,10 +202,10 @@ def _impl(Fc, Hc, grid_dt, restart_overhead, v_init=None, Pc=None, Elp=None,
     S = Fc.shape[0]
     Sc = 1.0 - Fc
     dead = Sc < 1e-6                                      # (S, T)
-    segs = seg_plan(j_max)
-    gp = candidate_grids(Fc, Hc, dt, j_max=j_max, t_max=t_max,
-                         delta_steps=delta_steps, Pc=Pc, Elp=Elp)
-    seg_data = [seg_views(gp, delta_steps, I) for I, _, _ in segs]
+    segs = _seg_plan(j_max)
+    gp = _candidate_grids(Fc, Hc, dt, j_max=j_max, t_max=t_max,
+                          delta_steps=delta_steps, Pc=Pc, Elp=Elp)
+    seg_data = [_seg_views(gp, delta_steps, I) for I, _, _ in segs]
 
     def one_sweep(carry, _):
         V_prev, _ = carry
@@ -217,8 +215,8 @@ def _impl(Fc, Hc, grid_dt, restart_overhead, v_init=None, Pc=None, Elp=None,
             # dollar mode: restart_overhead is the per-scenario (S,) dollar
             # overhead (hours x launch price, folded by the dispatcher)
             R = restart_overhead[:, None] + V_prev[:, :, 0]
-        VK = sweep_from_R(gp, seg_data, segs, R, dead, dt,
-                          j_max=j_max, t_max=t_max)
+        VK = _sweep_from_R(gp, seg_data, segs, R, dead, dt,
+                           j_max=j_max, t_max=t_max)
         return VK, None
 
     if v_init is None:

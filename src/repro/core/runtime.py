@@ -125,7 +125,6 @@ class RuntimeConfig:
     # checkpointing.solve_batch; "auto" keeps the platform default and the
     # REPRO_SOLVER_BACKEND env override
     solver_backend: str = "auto"
-    solver_refine: bool = False         # coarse-to-fine pre-sweep pruning
     # DP objective (see docs/solver.md): "makespan" optimises expected
     # hours-to-completion; "dollars" prices every segment off the live
     # ticker and optimises expected dollars-to-completion.  Dollars
@@ -280,8 +279,8 @@ class FleetRuntime:
             n_sweeps=cfg.warm_sweeps if warm else cfg.n_sweeps,
             restart_overhead=cfg.restart_overhead,
             v_init=self.live_tables.V if warm else None,
-            backend=cfg.solver_backend, refine=cfg.solver_refine,
-            objective=cfg.dp_objective, price=price)
+            backend=cfg.solver_backend, objective=cfg.dp_objective,
+            price=price)
         dt = time.perf_counter() - t0
         if dt > cfg.solve_budget_s:
             raise SolveTimeout(f"solve took {dt:.2f}s "

@@ -14,7 +14,7 @@ over the batched engine entry points.
 
 Sweep execution modes and their equivalence contract
 ----------------------------------------------------
-:func:`sweep_checkpointing` runs the same grid three ways, orderable by how
+:func:`sweep_checkpointing` runs the same grid two ways, differing in how
 much of it is folded into the leading batch axis (the engine's leading-axis
 convention):
 
@@ -26,15 +26,11 @@ convention):
     are stacked by ``engine.stack_policy_tables``, and a SINGLE
     scenario-batched executor dispatch produces every cell's makespans,
     which are then unflattened back to labeled rows.
-  * ``mode="grouped"`` — the PR-3 path: scenario axis batched, but the
-    (seed x policy) cell groups still loop in Python (P*R executor
-    dispatches).  Retained as the timed reference the one-kernel fold is
-    benchmarked against.
   * ``mode="serial"`` — the per-scenario reference path (one DP solve + one
     numpy pool round-trip + one executor call per cell group, scenario by
     scenario).  This is the semantic ground truth.
 
-All three modes emit identical row order and schema.  Equivalence contract
+Both modes emit identical row order and schema.  Equivalence contract
 (enforced by ``tests/test_batched.py`` / ``tests/test_scenarios.py``): DP
 tables and derived scalars (``expected_makespan_dp``, ``p_fail_fresh``) are
 bit-exact across modes at any dtype; with x64 enabled the makespan
@@ -46,9 +42,7 @@ NaN-flagged by the engine and excluded from row statistics, never silently
 averaged in; ``unfinished_frac`` records them per row in every mode.
 
 Adding a scenario is one :func:`register` call (see ROADMAP "Scenario
-sweeps"); ``benchmarks/scenario_sweep.py`` turns the default grid into the
-machine-readable ``BENCH_scenarios.json`` perf artifact (see
-``docs/bench_schemas.md``).
+sweeps").
 """
 from __future__ import annotations
 
@@ -252,8 +246,7 @@ def sweep_checkpointing(scenarios: Iterable, *,
                         restart_overhead: float = 0.0,
                         n_sweeps: int = 3, mode: str = "batched",
                         tables: Optional["ckpt.BatchDPTables"] = None,
-                        solver_backend: str = "auto",
-                        solver_refine: bool = False) -> list:
+                        solver_backend: str = "auto") -> list:
     """Expand (scenario x policy x seed) over the vectorized executor.
 
     ``mode="batched"`` (default) folds the WHOLE grid into the engine's
@@ -267,38 +260,32 @@ def sweep_checkpointing(scenarios: Iterable, *,
     is shared across the P policies of the same (scenario, seed) — exactly
     the sharing the serial path expresses with its nested loops.
 
-    ``mode="grouped"`` is the PR-3 path this replaced — scenario axis
-    batched, (seed x policy) cell groups looped in Python — retained as the
-    timed comparison point for ``benchmarks/scenario_sweep.py``.
     ``mode="serial"`` is the per-scenario reference path (one solve + one
     numpy pool round-trip per scenario): the semantic ground truth.
 
-    Row order and schema are identical in all modes; the equivalence
+    Row order and schema are identical in both modes; the equivalence
     contract between them (bit-exact DP scalars always; bit-identical rows
     under x64; float32-rounding-close otherwise) is stated in the module
     docstring and enforced by the test suite.  Truncated trials are
     NaN-flagged by the engine, never silently averaged in.
 
-    ``tables`` (batched/grouped modes) reuses a previously solved
+    ``tables`` (batched mode) reuses a previously solved
     ``checkpointing.BatchDPTables`` for this scenario list, skipping the DP
     solve entirely — the whole-grid *re-evaluation* path (fresh seeds,
     trial counts or policies against fixed market models) then costs only
     the pool draw and the single executor dispatch.
 
-    ``solver_backend``/``solver_refine`` pass straight through to
-    ``checkpointing.solve_batch`` (batched/grouped modes; the serial
-    reference path always runs the reference kernel) — see
-    ``docs/solver.md``.
+    ``solver_backend`` passes straight through to
+    ``checkpointing.solve_batch`` (batched mode; the serial reference path
+    always runs the reference kernel) — see ``docs/solver.md``.
     """
-    if mode not in ("batched", "grouped", "serial"):
-        raise ValueError(f"mode must be 'batched', 'grouped' or 'serial', "
-                         f"got {mode!r}")
+    if mode not in ("batched", "serial"):
+        raise ValueError(f"mode must be 'batched' or 'serial', got {mode!r}")
     scs = _resolve(scenarios)          # once: scenarios may be a generator
     if tables is not None:
         if mode == "serial":
-            raise ValueError("tables= reuse is for the batched/grouped "
-                             "modes; the serial reference path always "
-                             "re-solves")
+            raise ValueError("tables= reuse is for the batched mode; the "
+                             "serial reference path always re-solves")
         if len(tables) != len(scs) or tables.K.shape[1] != job_steps + 1:
             raise ValueError(
                 f"tables has {len(tables)} scenarios x j_max "
@@ -345,45 +332,20 @@ def sweep_checkpointing(scenarios: Iterable, *,
     batch = tables if tables is not None else ckpt.solve_batch(
         dist_list, job_steps, grid_dt=grid_dt, delta_steps=delta_steps,
         n_sweeps=n_sweeps, restart_overhead=restart_overhead,
-        backend=solver_backend, refine=solver_refine)
+        backend=solver_backend)
     ptables = {p: _policy_tables_batch(p, batch, job_steps, grid_dt,
                                        delta_steps, dist_list)
                for p in policies}
     p_fail_fresh = [float(d.cdf(job_steps * grid_dt)) for d in dist_list]
     S, P, R = len(scs), len(policies), len(seeds)
 
-    if mode == "grouped":
-        cells = {}
-        for seed in seeds:
-            first, pool = engine.draw_lifetime_pool_batch(
-                dist_list, n_trials, max_restarts=max_restarts, seed=seed)
-            for policy in policies:
-                mk, finished = engine.simulate_makespan_batch(
-                    ptables[policy], job_steps, first=first, pool=pool,
-                    grid_dt=grid_dt, delta_steps=delta_steps,
-                    restart_overhead=restart_overhead,
-                    max_restarts=max_restarts, unfinished="nan",
-                    return_finished=True)
-                cells[seed, policy] = (mk, finished)
-        for s, sc in enumerate(scs):             # serial-compatible row order
-            for seed in seeds:
-                for policy in policies:
-                    mk, finished = cells[seed, policy]
-                    rows.append(_ckpt_row(
-                        sc, policy, seed, mk[s], finished[s],
-                        n_trials=n_trials, job_steps=job_steps,
-                        p_fail_fresh=p_fail_fresh[s],
-                        expected_makespan_dp=batch.expected_makespan(
-                            s, job_steps)))
-        return rows
-
     # one-kernel fold: flat cell axis b = (s*R + r)*P + p, i.e. row order.
     # Pools depend on (scenario, seed) only, so the S*R unique pools are
     # drawn in one per-cell-seeded call; tables depend on (policy,
     # scenario) only.  Both stay deduplicated on device — the executor
     # fans them out to the B lanes through table_index/pool_index gathers
-    # (see the engine's "deduplicated fold" notes), which is what keeps
-    # the single dispatch faster than the grouped loop it replaces.
+    # (see the engine's "deduplicated fold" notes), so the gathers read the
+    # unique tables and pools instead of B replicated copies.
     first_sr, pool_sr = engine.draw_lifetime_pool_batch(
         [d for d in dist_list for _ in seeds], n_trials,
         max_restarts=max_restarts,
@@ -520,7 +482,6 @@ def solve_market_tables(scenarios: Iterable, market, *,
                         delta_steps: int = 1, n_sweeps: int = 3,
                         restart_overhead: float = 0.0,
                         solver_backend: str = "auto",
-                        solver_refine: bool = False,
                         dp_objective: str = "makespan") -> dict:
     """Solve one ``BatchDPTables`` per market regime, for ``tables=`` reuse.
 
@@ -548,8 +509,7 @@ def solve_market_tables(scenarios: Iterable, market, *,
         out[regime] = ckpt.solve_batch(
             dist_list, job_steps, grid_dt=grid_dt, delta_steps=delta_steps,
             n_sweeps=n_sweeps, restart_overhead=restart_overhead,
-            backend=solver_backend, refine=solver_refine,
-            objective=dp_objective, price=price)
+            backend=solver_backend, objective=dp_objective, price=price)
     return out
 
 
@@ -581,7 +541,6 @@ def sweep_market(scenarios: Iterable, *, market=None,
                  migrate_overhead_hours: float = 2.0 / 60.0,
                  cost_path: str = "kernel",
                  solver_backend: str = "auto",
-                 solver_refine: bool = False,
                  dp_objective: str = "makespan") -> list:
     """Expand (scenario x regime x cost-policy x seed) in dollars.
 
@@ -682,7 +641,7 @@ def sweep_market(scenarios: Iterable, *, market=None,
                 dist_list, job_steps, grid_dt=grid_dt,
                 delta_steps=delta_steps, n_sweeps=n_sweeps,
                 restart_overhead=restart_overhead, backend=solver_backend,
-                refine=solver_refine, objective=dp_objective,
+                objective=dp_objective,
                 price=g if dp_objective == "dollars" else None)
         # per-leaf expected cost of a fresh job (hours, or dollars under the
         # dollar objective) — the substitution policies' feasibility signal

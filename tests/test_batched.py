@@ -458,10 +458,9 @@ def test_sweep_tables_reuse_and_validation():
     scs = _sweep_scenarios()
     kw = dict(policies=("dp", "none"), seeds=(1,), job_steps=30, n_trials=20)
     batch = C.solve_batch([sc.dist() for sc in scs], 30, grid_dt=1.0 / 60.0)
-    for mode in ("batched", "grouped"):
-        _assert_rows_identical(
-            SC.sweep_checkpointing(scs, mode=mode, tables=batch, **kw),
-            SC.sweep_checkpointing(scs, mode=mode, **kw))
+    _assert_rows_identical(
+        SC.sweep_checkpointing(scs, mode="batched", tables=batch, **kw),
+        SC.sweep_checkpointing(scs, mode="batched", **kw))
     with pytest.raises(ValueError, match="serial reference"):
         SC.sweep_checkpointing(scs, mode="serial", tables=batch, **kw)
     with pytest.raises(ValueError, match="needs 2 x 40"):

@@ -208,27 +208,26 @@ def test_sweep_checkpointing_grid_shape_and_determinism():
 
 
 def test_sweep_checkpointing_modes_match_serial():
-    """The one-kernel fold (mode="batched") and the PR-3 grouped path must
-    both reproduce the serial per-scenario sweep: identical row
-    order/coords, bit-identical DP expectations and fresh-VM failure
-    probabilities, and makespan statistics within the pool's float32
-    inverse-CDF rounding (far below Monte-Carlo noise)."""
+    """The one-kernel fold (mode="batched") must reproduce the serial
+    per-scenario sweep: identical row order/coords, bit-identical DP
+    expectations and fresh-VM failure probabilities, and makespan
+    statistics within the pool's float32 inverse-CDF rounding (far below
+    Monte-Carlo noise)."""
     grid = SC.default_grid(vm_types=("n1-highcpu-16", "n1-highcpu-32"),
                            phases=("day", "night"), zones=("us-east1-b",))
     kw = dict(policies=("dp", "young_daly", "none"), seeds=(0, 1),
               job_steps=60, n_trials=80)
     serial = SC.sweep_checkpointing(grid, mode="serial", **kw)
-    for mode in ("batched", "grouped"):
-        rows = SC.sweep_checkpointing(grid, mode=mode, **kw)
-        assert len(rows) == len(serial) == len(grid) * 3 * 2
-        for b, s in zip(rows, serial):
-            assert (b["scenario"], b["policy"], b["seed"]) == \
-                (s["scenario"], s["policy"], s["seed"])
-            assert b["expected_makespan_dp"] == s["expected_makespan_dp"]
-            assert b["p_fail_fresh"] == s["p_fail_fresh"]
-            assert b["unfinished_frac"] == s["unfinished_frac"] == 0.0
-            np.testing.assert_allclose(b["makespan_mean"],
-                                       s["makespan_mean"], rtol=5e-3)
+    rows = SC.sweep_checkpointing(grid, mode="batched", **kw)
+    assert len(rows) == len(serial) == len(grid) * 3 * 2
+    for b, s in zip(rows, serial):
+        assert (b["scenario"], b["policy"], b["seed"]) == \
+            (s["scenario"], s["policy"], s["seed"])
+        assert b["expected_makespan_dp"] == s["expected_makespan_dp"]
+        assert b["p_fail_fresh"] == s["p_fail_fresh"]
+        assert b["unfinished_frac"] == s["unfinished_frac"] == 0.0
+        np.testing.assert_allclose(b["makespan_mean"],
+                                   s["makespan_mean"], rtol=5e-3)
     with pytest.raises(ValueError, match="mode"):
         SC.sweep_checkpointing(grid, mode="bogus", **kw)
 

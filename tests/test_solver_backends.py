@@ -1,8 +1,8 @@
 """Solver backend equivalence: the bit-exactness contract across the
-pluggable backends (reference vs xla vs coarse-to-fine), Pallas interpret
-tolerance, backend selection/env-override rules, scenario sharding
-transparency, and the FleetRuntime mid-sweep backend swap pinning the
-``v_init`` warm-start semantics."""
+pluggable backends (reference vs xla), Pallas interpret tolerance, backend
+selection/env-override rules, the sweep runners' ``solver_backend``
+pass-through, scenario sharding transparency, and the FleetRuntime
+mid-sweep backend swap pinning the ``v_init`` warm-start semantics."""
 import dataclasses
 import json
 import os
@@ -17,9 +17,9 @@ import pytest
 from repro.core import distributions as D
 from repro.core import market as M
 from repro.core import runtime as rt
+from repro.core import scenarios as SC
 from repro.core.policies import checkpointing as C
 from repro.core.policies import solver_backends as SB
-from repro.core.policies.solver_backends import refine as R
 from repro.kernels import dp_recurrence as DP
 
 GRID = 1.0 / 12.0
@@ -30,8 +30,8 @@ RO = 0.3          # restart overhead (hours) — exercises launch-priced R_j
 @pytest.fixture(scope="module")
 def dists():
     # mixed hazards on one deadline: constrained (the paper's family),
-    # memoryless, and a decreasing-hazard Weibull whose run-to-completion
-    # argmins exercise the refine caps' graceful degradation
+    # memoryless, and a decreasing-hazard Weibull whose argmins often run
+    # the whole remaining job in one segment
     return [D.constrained_for("n1-highcpu-16"), D.Exponential(mttf=8.0),
             D.Weibull(lam=0.12, k=0.8)]
 
@@ -42,7 +42,7 @@ def plain(dists):
 
 
 # ---------------------------------------------------------------------------
-# bit-identity: reference vs xla vs coarse-to-fine (x64 session dtype)
+# bit-identity: reference vs xla (x64 session dtype), warm-start chains
 # ---------------------------------------------------------------------------
 
 def test_reference_vs_xla_bit_identical_x64(dists):
@@ -59,54 +59,18 @@ def test_reference_vs_xla_bit_identical_x64(dists):
     assert np.array_equal(ref.K, xla.K)
 
 
-def test_refined_verified_tables_bit_identical_x64(dists):
-    """Coarse-to-fine with a passing verification is the plain solve: same
-    V, same K, to the bit."""
-    with jax.enable_x64(True):
-        plain = C.solve_batch(dists, JOB, grid_dt=GRID)
-        ctf = C.solve_batch(dists, JOB, grid_dt=GRID, refine=True,
-                            refine_check="full")
-    info = ctf.refine_info
-    assert info["applied"] and info["verified_col0"]
-    assert not info["fallback"]
-    assert info["full_check_match"]
-    assert ctf.backend == "xla+refine"
-    assert np.array_equal(plain.V, ctf.V)
-    assert np.array_equal(plain.K, ctf.K)
-
-
-def test_refined_warm_start_chain(dists, plain):
-    """Refined pre-sweeps reproduce the warm-start fixed-point chain too:
-    2 warm sweeps (refined) from a 3-sweep cold V == 5-sweep cold solve."""
-    warm = C.solve_batch(dists, JOB, grid_dt=GRID, n_sweeps=2,
-                         v_init=plain.V, refine=True)
-    cold5 = C.solve_batch(dists, JOB, grid_dt=GRID, n_sweeps=5)
-    assert warm.refine_info["applied"]
-    assert not warm.refine_info["fallback"]
+@pytest.mark.parametrize("objective", ["makespan", "dollars"])
+def test_warm_start_chain(dists, price, objective):
+    """Warm starts stay inside one objective's fixed-point chain: 2 warm
+    sweeps from a 3-sweep cold V == 5-sweep cold solve."""
+    kw = dict(grid_dt=GRID, restart_overhead=RO, objective=objective,
+              price=price if objective == "dollars" else None)
+    cold3 = C.solve_batch(dists, JOB, n_sweeps=3, **kw)
+    warm = C.solve_batch(dists, JOB, n_sweeps=2, v_init=cold3.V, **kw)
+    cold5 = C.solve_batch(dists, JOB, n_sweeps=5, **kw)
+    assert warm.objective == objective
     assert np.array_equal(warm.V, cold5.V)
     assert np.array_equal(warm.K, cold5.K)
-
-
-def test_refined_fallback_on_sabotaged_caps(dists, plain, monkeypatch):
-    """Force every candidate cap to 1 so the pre-sweeps must miss argmins:
-    the column-0 verification has to catch it and the dispatcher has to
-    serve the plain solve."""
-    monkeypatch.setattr(R, "candidate_caps",
-                        lambda Kc, segs, **kw: (1,) * len(segs))
-    ctf = C.solve_batch(dists, JOB, grid_dt=GRID, refine=True)
-    assert not ctf.refine_info["verified_col0"]
-    assert ctf.refine_info["fallback"]
-    assert np.array_equal(plain.V, ctf.V)
-    assert np.array_equal(plain.K, ctf.K)
-
-
-def test_refine_plan_degenerate_and_bad_backend(dists):
-    small = C.solve_batch(dists, 6, grid_dt=1.0, refine=True)
-    assert small.refine_info == {"applied": False, "reason": "degenerate"}
-    assert R.plan(300, 1440, 1, 1, 4, None) is None     # no pre-sweeps
-    with pytest.raises(ValueError, match="contradictory"):
-        C.solve_batch(dists, JOB, grid_dt=GRID, refine=True,
-                      backend="pallas")
 
 
 # ---------------------------------------------------------------------------
@@ -140,36 +104,6 @@ def test_dollar_reference_vs_xla_bit_identical_x64(dists, price):
     assert np.array_equal(ref.V, xla.V)
     assert np.array_equal(ref.K, xla.K)
     ref.validate()
-
-
-def test_dollar_refined_verified_bit_identical_x64(dists, price):
-    """Coarse-to-fine under the dollar objective (the coarse hint solve runs
-    dollars too) with a passing full check equals the plain dollar solve."""
-    with jax.enable_x64(True):
-        # refine always runs on the XLA machinery, so compare against an
-        # explicit xla plain solve (env-robust under the backend matrix)
-        plain = C.solve_batch(dists, JOB, grid_dt=GRID, restart_overhead=RO,
-                              objective="dollars", price=price,
-                              backend="xla")
-        ctf = C.solve_batch(dists, JOB, grid_dt=GRID, restart_overhead=RO,
-                            objective="dollars", price=price, refine=True,
-                            refine_check="full")
-    assert ctf.refine_info["applied"] and not ctf.refine_info["fallback"]
-    assert ctf.refine_info["full_check_match"]
-    assert np.array_equal(plain.V, ctf.V)
-    assert np.array_equal(plain.K, ctf.K)
-
-
-def test_dollar_warm_start_chain(dists, price):
-    """Warm starts stay inside one objective's fixed-point chain: 2 warm
-    sweeps from a 3-sweep dollar V == 5-sweep cold dollar solve."""
-    kw = dict(grid_dt=GRID, restart_overhead=RO, objective="dollars",
-              price=price)
-    cold3 = C.solve_batch(dists, JOB, n_sweeps=3, **kw)
-    warm = C.solve_batch(dists, JOB, n_sweeps=2, v_init=cold3.V, **kw)
-    cold5 = C.solve_batch(dists, JOB, n_sweeps=5, **kw)
-    assert np.array_equal(warm.V, cold5.V)
-    assert np.array_equal(warm.K, cold5.K)
 
 
 def test_dollar_flat_price_reduces_to_makespan(dists):
@@ -245,12 +179,8 @@ def test_dollar_sharding_single_device_mesh_transparent(dists, price):
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     with jax.set_mesh(mesh), sh.use(mesh):
         shd = C.solve_batch(dists, JOB, **kw)
-        ctf = C.solve_batch(dists, JOB, refine=True,
-                            **{**kw, "backend": "auto"})
     assert np.array_equal(plain.V, shd.V)
     assert np.array_equal(plain.K, shd.K)
-    assert not ctf.refine_info["fallback"]
-    assert np.array_equal(plain.V, ctf.V)
 
 
 # ---------------------------------------------------------------------------
@@ -394,6 +324,63 @@ def test_pallas_refresh_loop_traces_twice():
 
 
 # ---------------------------------------------------------------------------
+# sweep runners: solver_backend passes through to solve_batch
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("runner", ["sweep_checkpointing",
+                                    "solve_market_tables", "sweep_market"])
+def test_sweep_runner_backend_reference_equals_xla_x64(runner, monkeypatch):
+    """Each sweep runner hands ``solver_backend`` to every DP solve it
+    makes, and under x64 the reference and xla backends give the same
+    tables, hence the same rows, bit for bit."""
+    scs = SC.default_grid(vm_types=("n1-highcpu-16",), phases=("day",))
+    solved = []
+    solve_batch = C.solve_batch
+
+    def spy(*a, **kw):
+        tab = solve_batch(*a, **kw)
+        solved.append(tab.backend)
+        return tab
+
+    monkeypatch.setattr(C, "solve_batch", spy)
+    kw = dict(job_steps=30, grid_dt=GRID)
+    sim = dict(seeds=(0,), n_trials=16, max_restarts=8)
+
+    def run(backend):
+        solved.clear()
+        if runner == "sweep_checkpointing":
+            out = SC.sweep_checkpointing(scs, solver_backend=backend,
+                                         **kw, **sim)
+        elif runner == "solve_market_tables":
+            out = SC.solve_market_tables(
+                scs, M.MarketModel.for_scenarios(scs),
+                solver_backend=backend, **kw)
+        else:
+            out = SC.sweep_market(
+                scs, market=M.MarketModel.for_scenarios(scs),
+                solver_backend=backend, **kw, **sim)
+        assert solved and set(solved) == {backend}
+        return out
+
+    with jax.enable_x64(True):
+        ref, xla = run("reference"), run("xla")
+    if runner == "solve_market_tables":
+        assert ref.keys() == xla.keys() == {"calm", "crunch"}
+        for regime in ref:
+            assert np.array_equal(ref[regime].V, xla[regime].V)
+            assert np.array_equal(ref[regime].K, xla[regime].K)
+    else:
+        assert len(ref) == len(xla) > 0
+        for a, b in zip(ref, xla):
+            assert a.keys() == b.keys()
+            for k, va in a.items():
+                if isinstance(va, float) and np.isnan(va):
+                    assert np.isnan(b[k]), k
+                else:
+                    assert va == b[k], (k, va, b[k])
+
+
+# ---------------------------------------------------------------------------
 # scenario sharding
 # ---------------------------------------------------------------------------
 
@@ -406,11 +393,8 @@ def test_sharding_single_device_mesh_transparent(dists, plain):
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     with jax.set_mesh(mesh), sh.use(mesh):
         shd = C.solve_batch(dists, JOB, grid_dt=GRID)
-        ctf = C.solve_batch(dists, JOB, grid_dt=GRID, refine=True)
     assert np.array_equal(plain.V, shd.V)
     assert np.array_equal(plain.K, shd.K)
-    assert not ctf.refine_info["fallback"]
-    assert np.array_equal(plain.V, ctf.V)
 
 
 def test_sharding_no_mesh_returns_unwrapped():
@@ -422,8 +406,7 @@ def test_sharding_no_mesh_returns_unwrapped():
 @pytest.mark.slow
 def test_sharding_two_devices_bit_identical():
     """Real shard_map over 2 forced host devices: the sharded S=4 solve
-    (plain and refined) must equal the unsharded single-device tables
-    bit-for-bit; an indivisible S=3 falls back transparently; the CDF grids
+    must equal the unsharded single-device tables bit-for-bit; an indivisible S=3 falls back transparently; the CDF grids
     are built on the host device, not on the caller's mesh."""
     code = textwrap.dedent("""
         import os
@@ -448,8 +431,6 @@ def test_sharding_two_devices_bit_identical():
         mesh = Mesh(np.array(jax.devices()).reshape(2), ("data",))
         with jax.set_mesh(mesh), sh.use(mesh):
             shd = C.solve_batch(dists, 30, grid_dt=1.0 / 6.0, n_sweeps=2)
-            ctf = C.solve_batch(dists, 30, grid_dt=1.0 / 6.0, n_sweeps=2,
-                                refine=True)
             p3 = C.solve_batch(dists[:3], 30, grid_dt=1.0 / 6.0, n_sweeps=2)
         D.Exponential.cdf = cdf
         u3 = C.solve_batch(dists[:3], 30, grid_dt=1.0 / 6.0, n_sweeps=2)
@@ -457,8 +438,6 @@ def test_sharding_two_devices_bit_identical():
             "devices": jax.device_count(),
             "plain_eq": bool(np.array_equal(plain.V, shd.V)
                              and np.array_equal(plain.K, shd.K)),
-            "refine_eq": bool(np.array_equal(plain.V, ctf.V)),
-            "refine_ok": bool(not ctf.refine_info["fallback"]),
             "indivisible_eq": bool(np.array_equal(p3.V, u3.V)),
             "grids_on_host": bool(grid_devices) and all(
                 ids == [0] for ids in grid_devices),
@@ -470,9 +449,8 @@ def test_sharding_two_devices_bit_identical():
                          text=True, env=env, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
     result = json.loads(out.stdout.strip().splitlines()[-1])
-    assert result == {"devices": 2, "plain_eq": True, "refine_eq": True,
-                      "refine_ok": True, "indivisible_eq": True,
-                      "grids_on_host": True}
+    assert result == {"devices": 2, "plain_eq": True,
+                      "indivisible_eq": True, "grids_on_host": True}
 
 
 # ---------------------------------------------------------------------------
@@ -483,7 +461,7 @@ def test_runtime_mid_sweep_backend_swap_pins_v_init(monkeypatch):
     """Swapping the solver backend between refits must not disturb the
     warm-start chain: the fixed point couples backends only through V, so
     warm sweeps on a DIFFERENT backend continue the cold sweep sequence
-    bit-exactly (reference/xla/refined are interchangeable mid-loop)."""
+    bit-exactly (reference and xla are interchangeable mid-loop)."""
     cfg = dict(job_steps=40, grid_dt=0.25, window=128, refit_every=32,
                min_samples=48, stream_block=128, regret_trials=32,
                stream_vm_types=("n1-highcpu-2",), solver_backend="xla")
@@ -492,11 +470,9 @@ def test_runtime_mid_sweep_backend_swap_pins_v_init(monkeypatch):
     cold = fr.live_tables                      # n_sweeps=3 cold solve, xla
     want = C.solve_batch(dists, cfg["job_steps"], grid_dt=cfg["grid_dt"],
                          n_sweeps=3 + fr.cfg.warm_sweeps)
-    for swap in ({"solver_backend": "reference"},
-                 {"solver_backend": "auto", "solver_refine": True}):
-        fr.cfg = dataclasses.replace(fr.cfg, **swap)
-        tab = fr._solve(warm=True)             # warm_sweeps=2 from cold.V
-        assert fr._last_solve_warm, swap
-        assert np.array_equal(tab.V, want.V), swap
-        assert np.array_equal(tab.K, want.K), swap
+    fr.cfg = dataclasses.replace(fr.cfg, solver_backend="reference")
+    tab = fr._solve(warm=True)                 # warm_sweeps=2 from cold.V
+    assert fr._last_solve_warm
+    assert np.array_equal(tab.V, want.V)
+    assert np.array_equal(tab.K, want.K)
     assert np.array_equal(cold.V, fr.live_tables.V)  # swap did not publish
