@@ -3,9 +3,13 @@
 Mechanics:
   * pytrees are flattened to path->array dicts and written as .npz with a
     JSON manifest carrying shapes/dtypes/CRC32s and user metadata;
-  * writes are atomic (tmp dir + rename) and optionally asynchronous (the
-    device->host copy happens synchronously, the disk write on a thread -
-    on TPU fleets the same split hides the object-store upload);
+  * writes are atomic (tmp dir + rename) and optionally asynchronous: the
+    calling thread makes the device->host copy and nothing else (the next
+    step donates the state); a writer thread checksums each leaf once on
+    its host buffer, leaves spread over a few threads, and writes it
+    straight from that buffer into a stored zip64 ``.npz`` whose member
+    CRCs are derived from the leaves' (``save_bytes`` counts the bytes) -
+    on TPU fleets the same split hides the object-store upload;
   * ``restore_latest`` scans the directory and returns the newest intact
     checkpoint, reading each leaf once from the file straight into the
     host array it returns and checking its CRC32 there (``restore_bytes``
@@ -26,6 +30,8 @@ selected for baselines (EXPERIMENTS.md compares them).
 from __future__ import annotations
 
 import dataclasses
+import functools
+import io
 import json
 import math
 import os
@@ -36,6 +42,7 @@ import threading
 import time
 import zipfile
 import zlib
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Optional
 
 import jax
@@ -77,23 +84,33 @@ def _unflatten_like(template, flat: dict):
 
 def save_checkpoint(directory: str, step: int, tree, metadata: Optional[dict]
                     = None, *, blocking: bool = True) -> threading.Thread:
-    """Atomic (tmp+rename) checkpoint write; returns the writer thread."""
+    """Atomic (tmp+rename) checkpoint write; returns the writer thread.
+
+    The calling thread makes the host copy (``jax.device_get``) and nothing
+    else; the checksums, the manifest and the write run on the writer
+    thread, which ``blocking`` joins before returning."""
     os.makedirs(directory, exist_ok=True)
     flat = _flatten(jax.device_get(tree))  # host copy is synchronous
-    manifest = {
-        "step": int(step),
-        "time": time.time(),
-        "metadata": metadata or {},
-        "arrays": {k: {"shape": list(v.shape), "dtype": str(v.dtype),
-                       "crc32": zlib.crc32(np.ascontiguousarray(v).tobytes())}
-                   for k, v in flat.items()},
-    }
+    when = time.time()
 
     def write():
+        global _save_bytes
         tmp = tempfile.mkdtemp(dir=directory, prefix=".tmp_")
         try:
-            np.savez(os.path.join(tmp, "arrays.npz"),
-                     **{k: v for k, v in flat.items()})
+            with ThreadPoolExecutor(_CRC_THREADS) as pool:
+                crcs = _write_npz(os.path.join(tmp, "arrays.npz"), flat,
+                                  pool.map(_checksummed, flat.values()),
+                                  when)
+            with _save_lock:
+                _save_bytes += sum(v.nbytes for v in flat.values())
+            manifest = {
+                "step": int(step),
+                "time": when,
+                "metadata": metadata or {},
+                "arrays": {k: {"shape": list(v.shape), "dtype": str(v.dtype),
+                               "crc32": crcs[k]}
+                           for k, v in flat.items()},
+            }
             with open(os.path.join(tmp, "manifest.json"), "w") as f:
                 json.dump(manifest, f)
             final = os.path.join(directory, f"step_{int(step):010d}")
@@ -110,6 +127,136 @@ def save_checkpoint(directory: str, step: int, tree, metadata: Optional[dict]
     if blocking:
         t.join()
     return t
+
+
+_CRC_THREADS = min(8, os.cpu_count() or 1)
+_save_lock = threading.Lock()
+_save_bytes = 0
+
+
+def save_bytes() -> int:
+    """Leaf bytes checksummed and written by saves in this process: a save
+    adds the total size of its manifest's arrays, once."""
+    return _save_bytes
+
+
+def _checksummed(v: np.ndarray) -> tuple[np.ndarray, int]:
+    """``v`` in C order (a copy only if it is not C-contiguous already)
+    and the CRC32 of its bytes, computed on that buffer."""
+    arr = v if v.flags.c_contiguous else np.array(v, order="C")
+    return arr, zlib.crc32(arr)
+
+
+# ---------------------------------------------------------------------------
+# the .npz writer: a stored-only zip64 archive of .npy 1.0 members, each
+# array's buffer written once, its CRC32 derived from the leaf's
+# ---------------------------------------------------------------------------
+
+_ZIP64_AT = 0xFFFFFFFF   # sizes and offsets from here on go in zip64 extras
+_ZIP_LOCAL = struct.Struct("<IHHHHHIIIHH")
+_ZIP_CENTRAL = struct.Struct("<IHHHHHHIIIHHHHHII")
+_ZIP64_END = struct.Struct("<IQHHIIQQQQ")
+_ZIP64_LOCATOR = struct.Struct("<IIQI")
+_ZIP_END = struct.Struct("<IHHHHIIH")
+_UTF8_NAME = 0x800
+
+
+def _gf2_times(mat, vec: int) -> int:
+    out, i = 0, 0
+    while vec:
+        if vec & 1:
+            out ^= mat[i]
+        vec >>= 1
+        i += 1
+    return out
+
+
+def _gf2_square(mat) -> tuple:
+    return tuple(_gf2_times(mat, row) for row in mat)
+
+
+@functools.cache
+def _crc_zeros(k: int) -> tuple:
+    """The operator that feeds ``2**k`` zero bytes through a CRC32
+    register, as 32 columns over GF(2): squared up from one zero bit."""
+    if k:
+        return _gf2_square(_crc_zeros(k - 1))
+    op = (0xEDB88320,) + tuple(1 << i for i in range(31))
+    for _ in range(3):   # 2, 4, 8 bits
+        op = _gf2_square(op)
+    return op
+
+
+def crc32_combine(crc1: int, crc2: int, len2: int) -> int:
+    """``zlib.crc32(a + b)`` from ``crc1 = zlib.crc32(a)``, ``crc2 =
+    zlib.crc32(b)`` and ``len2 = len(b)``: a port of zlib's
+    ``crc32_combine``, which Python's ``zlib`` does not expose."""
+    for k in range(len2.bit_length()):
+        if len2 >> k & 1:
+            crc1 = _gf2_times(_crc_zeros(k), crc1)
+    return crc1 ^ crc2
+
+
+def _u32(v: int) -> int:
+    return v if v < _ZIP64_AT else 0xFFFFFFFF
+
+
+def _zip64_extra(*values: int) -> bytes:
+    return struct.pack(f"<HH{len(values)}Q", 1, 8 * len(values), *values) \
+        if values else b""
+
+
+def _write_member(f, key: str, arr: np.ndarray, crc: int, dos: tuple) -> bytes:
+    """Writes ``arr`` (C-contiguous; ``crc`` the CRC32 of its bytes) as the
+    stored member ``key.npy`` at ``f``'s position: the local header, the
+    ``.npy`` 1.0 header, then the array's buffer, once.  Returns the
+    member's central directory record."""
+    offset = f.tell()
+    head = io.BytesIO()
+    np.lib.format.write_array_header_1_0(
+        head, np.lib.format.header_data_from_array_1_0(arr))
+    head = head.getvalue()
+    size = len(head) + arr.nbytes
+    crc = crc32_combine(zlib.crc32(head), crc, arr.nbytes)
+    name = (key + ".npy").encode()
+    sizes = (size, size) if size >= _ZIP64_AT else ()
+    far = (offset,) if offset >= _ZIP64_AT else ()
+    version = 45 if sizes or far else 20
+    local = _zip64_extra(*sizes)
+    f.write(_ZIP_LOCAL.pack(0x04034B50, version, _UTF8_NAME, 0, *dos, crc,
+                            _u32(size), _u32(size), len(name), len(local))
+            + name + local)
+    f.write(head)
+    f.write(arr)
+    central = _zip64_extra(*sizes, *far)
+    return _ZIP_CENTRAL.pack(
+        0x02014B50, 3 << 8 | version, version, _UTF8_NAME, 0, *dos, crc,
+        _u32(size), _u32(size), len(name), len(central), 0, 0, 0,
+        0o600 << 16, _u32(offset)) + name + central
+
+
+def _write_npz(path: str, flat: dict, checksummed, when: float) -> dict:
+    """Writes ``flat``'s leaves, as ``checksummed`` yields them in order
+    (``(array, crc32)``), into a new ``.npz`` at ``path``; returns each
+    key's CRC32."""
+    t = time.localtime(when)
+    dos = (t.tm_hour << 11 | t.tm_min << 5 | t.tm_sec // 2,
+           max(t.tm_year - 1980, 0) << 9 | t.tm_mon << 5 | t.tm_mday)
+    crcs, records = {}, []
+    with open(path, "wb") as f:
+        for key, (arr, crc) in zip(flat, checksummed):
+            records.append(_write_member(f, key, arr, crc, dos))
+            crcs[key] = crc
+        start = f.tell()
+        f.write(b"".join(records))
+        end, n = f.tell(), len(records)
+        f.write(_ZIP64_END.pack(0x06064B50, 44, 3 << 8 | 45, 45, 0, 0, n, n,
+                                end - start, start))
+        f.write(_ZIP64_LOCATOR.pack(0x07064B50, 0, end, 1))
+        f.write(_ZIP_END.pack(0x06054B50, 0, 0, min(n, 0xFFFF),
+                              min(n, 0xFFFF), _u32(end - start),
+                              _u32(start), 0))
+    return crcs
 
 
 def prune(directory: str, keep: int = KEEP) -> None:

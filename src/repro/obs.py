@@ -20,8 +20,10 @@ The spans, from the closed loop and the trainer down:
   repro.train.step       train(): every later step, batch to loss on host
   repro.train.resume     train(): replace the pod, restore, recompute the
                          schedule after a preemption
-  repro.ckpt.save        CheckpointManager.save (waits, copies, checks,
-                         and writes when blocking)
+  repro.ckpt.save        CheckpointManager.save: waits, copies the state to
+                         the host; checksums and writes only when blocking
+                         (else on the writer thread; bytes:
+                         checkpoint.manager.save_bytes())
   repro.ckpt.wait        CheckpointManager.wait on a writer in flight
   repro.ckpt.restore     restore_latest: each leaf read once into its host
                          array and CRC-checked there (bytes read:

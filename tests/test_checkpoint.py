@@ -2,7 +2,11 @@
 import io
 import json
 import os
+import random
 import shutil
+import threading
+import zipfile
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -120,6 +124,13 @@ def test_shape_unlike_template_falls_back_to_older(tmpdir):
     assert restore_latest(tmpdir, _tree())[1] == 10
 
 
+def _manifest_bytes(path) -> int:
+    with open(os.path.join(path, "manifest.json")) as f:
+        arrays = json.load(f)["arrays"]
+    return sum(int(np.prod(a["shape"])) * np.dtype(a["dtype"]).itemsize
+               for a in arrays.values())
+
+
 def test_restore_reads_each_leaf_once_bit_exact(tmpdir):
     """A restore of an intact checkpoint reads the manifest's array bytes
     exactly once, and hands back every leaf bit for bit with the
@@ -131,10 +142,7 @@ def test_restore_reads_each_leaf_once_bit_exact(tmpdir):
             "f": np.asfortranarray(np.arange(6.0).reshape(2, 3)),
             "s": np.array(-2.5, np.float32)}
     save_checkpoint(tmpdir, 4, tree)
-    with open(os.path.join(tmpdir, "step_0000000004", "manifest.json")) as f:
-        arrays = json.load(f)["arrays"]
-    total = sum(int(np.prod(a["shape"])) * np.dtype(a["dtype"]).itemsize
-                for a in arrays.values())
+    total = _manifest_bytes(os.path.join(tmpdir, "step_0000000004"))
     before = manager.restore_bytes()
     restored, step, _ = restore_latest(tmpdir, tree)
     assert step == 4
@@ -174,6 +182,107 @@ def test_writer_keeps_newest_two(tmpdir):
     assert step == 30
     np.testing.assert_array_equal(np.asarray(restored["a"]),
                                   np.asarray(_tree(30)["a"]))
+
+
+@pytest.mark.parametrize("zip64_at", [None, 1], ids=["plain", "zip64"])
+def test_archive_is_a_valid_npz(tmpdir, monkeypatch, zip64_at):
+    """The written ``arrays.npz`` is a stored zip that ``zipfile`` accepts
+    (every member's CRC checks) and ``np.load`` reads leaf for leaf, with
+    zip64 extras for sizes and offsets (forced from byte 1 in ``zip64``)."""
+    from repro.checkpoint import manager
+    if zip64_at is not None:
+        monkeypatch.setattr(manager, "_ZIP64_AT", zip64_at)
+    tree = {"f32": jax.random.normal(jax.random.PRNGKey(1), (3, 17)),
+            "bf16": jnp.linspace(-2, 2, 10, dtype=jnp.bfloat16),
+            "int": jnp.arange(12, dtype=jnp.int32).reshape(4, 3),
+            "empty": jnp.zeros((0, 5)),
+            "scalar": np.array(-1.5, np.float32),
+            "fortran": np.asfortranarray(np.arange(12.0).reshape(3, 4))}
+    save_checkpoint(tmpdir, 2, tree)
+    npz = os.path.join(tmpdir, "step_0000000002", "arrays.npz")
+    with zipfile.ZipFile(npz) as z:
+        assert z.testzip() is None
+        assert all(i.compress_type == zipfile.ZIP_STORED
+                   for i in z.infolist())
+    with np.load(npz) as z:
+        assert sorted(z.files) == sorted(tree)
+        for key, leaf in tree.items():
+            a, b = np.asarray(leaf), z[key]
+            assert b.shape == a.shape
+            # numpy names bfloat16 in a .npy header only as raw V2
+            assert b.dtype == (a.dtype if key != "bf16" else np.dtype("V2"))
+            assert np.ascontiguousarray(b).tobytes() == \
+                np.ascontiguousarray(a).tobytes()
+
+
+def test_crc32_combine_matches_crc32_of_concatenation():
+    from repro.checkpoint.manager import crc32_combine
+    rng = random.Random(7)
+    data = rng.randbytes(70_000)
+    cuts = [0, 1, len(data) - 1, len(data)] + \
+        [rng.randrange(len(data) + 1) for _ in range(60)]
+    for cut in cuts:
+        for start in (0, cut // 2, cut):   # the first part may be empty
+            a, b = data[start:cut], data[cut:]
+            assert crc32_combine(zlib.crc32(a), zlib.crc32(b), len(b)) \
+                == zlib.crc32(a + b), (start, cut)
+    assert crc32_combine(zlib.crc32(b""), zlib.crc32(b""), 0) == 0
+
+
+def test_save_bytes_counts_each_save_once(tmpdir):
+    from repro.checkpoint import manager
+    before = manager.save_bytes()
+    save_checkpoint(tmpdir, 1, _tree(1))
+    once = manager.save_bytes() - before
+    assert once == _manifest_bytes(os.path.join(tmpdir, "step_0000000001"))
+    th = save_checkpoint(tmpdir, 2, _tree(2), blocking=False)
+    th.join(timeout=60)
+    assert not th.is_alive()
+    assert manager.save_bytes() - before == 2 * once
+
+
+def test_async_save_returns_after_the_host_copy_alone(tmpdir, monkeypatch):
+    """With the writer held in its first member, the caller's save has
+    returned having run no crc32, and a jitted step that donates the saved
+    state overwrites it; after ``wait`` the checkpoint restores the state
+    at the save bit for bit."""
+    from repro.checkpoint import manager
+    held, entered = threading.Event(), threading.Event()
+    real_member, real_crc32 = manager._write_member, zlib.crc32
+    crc_threads = []
+
+    def held_member(*a):
+        entered.set()
+        assert held.wait(60)
+        return real_member(*a)
+
+    def crc32(*a):
+        crc_threads.append(threading.get_ident())
+        return real_crc32(*a)
+
+    monkeypatch.setattr(manager, "_write_member", held_member)
+    monkeypatch.setattr(manager.zlib, "crc32", crc32)
+    state = _tree(5)
+    at_save = jax.tree_util.tree_map(lambda x: np.array(x, copy=True),
+                                     state)
+    mgr = CheckpointManager(directory=tmpdir, dist=D.constrained_for(),
+                            policy="none")
+    mgr.save(9, state)
+    assert entered.wait(60) and mgr._writer.is_alive()
+    assert threading.get_ident() not in crc_threads
+    step = jax.jit(lambda t: jax.tree_util.tree_map(lambda x: x * 3 + 1, t),
+                   donate_argnums=0)
+    state = jax.block_until_ready(step(state))
+    held.set()
+    mgr.wait()
+    assert crc_threads and threading.get_ident() not in crc_threads
+    monkeypatch.setattr(manager.zlib, "crc32", real_crc32)
+    restored, step_no, _ = restore_latest(tmpdir, at_save)
+    assert step_no == 9
+    for a, b in zip(jax.tree_util.tree_leaves(at_save),
+                    jax.tree_util.tree_leaves(restored)):
+        assert b.dtype == a.dtype and b.shape == a.shape
+        assert np.asarray(b).tobytes() == a.tobytes()
 
 
 def _mgr(tmpdir, policy, **kw):
